@@ -1,14 +1,14 @@
 """Benchmark harness — one section per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV.  All sections run on the host CPU (the
-TARGET is TPU; these benchmarks validate relative behaviour — scan strategy
-ratios, traffic counts, operator scaling — rather than absolute device numbers;
-the TPU-side projection lives in EXPERIMENTS.md §Roofline).
+Prints ``name,us_per_call,derived`` CSV.  Timings are of whatever backend JAX
+runs on; the committed ``benchmarks/baseline`` rows are CPU-host timings, which
+validate relative behaviour — scan strategy ratios, traffic counts, operator
+scaling — and are not device numbers.
 
   Fig 3  single-core scan: vector CumSum vs ScanU vs ScanUL1
   Fig 5  batched scan: ScanUL1/ScanU execution-time ratio grid
-  Fig 8  MCScan bandwidth vs length (s = 32/64/128) + copy roofline  [8 devices]
-  Fig 9  MCScan int8 vs fp16 GElems/s                                [8 devices]
+  Fig 8  MCScan bandwidth vs length (s = 32/64/128) + copy roofline  [>= 2 devices]
+  Fig 9  MCScan int8 vs fp16 GElems/s                                [>= 2 devices]
   Fig 10 compress vs baseline masked-select
   Fig 11 radix sort vs jnp.sort (fp16)
   Fig 12 batched scan bandwidth vs batch size (len 65K)
@@ -37,19 +37,23 @@ the TPU-side projection lives in EXPERIMENTS.md §Roofline).
                  tokens/s + p50/p99 per-token step latency + page-pool
                  utilization, plus a trace-only guard that decode_n stages
                  exactly one while_loop -> BENCH_serve.json
-  dist           distributed operator family at 8 virtual devices:
+  dist           distributed operator family at 8 devices:
                  measured (HLO-parsed) vs modeled (closed-form) collective
                  traffic per dist_* op, gated exactly in-run
-                 -> BENCH_dist.json                                [8 devices]
+                 -> BENCH_dist.json                                [>= 2 devices]
+
+Every section runs in this one process.  The multi-device sections build
+their mesh from ``jax.devices()`` and print a skip line with fewer than 2; on
+a CPU host give the command 8 virtual devices:
+
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 python benchmarks/run.py
 """
 from __future__ import annotations
 
 import argparse
 import functools
 import os
-import subprocess
 import sys
-import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +65,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from benchmarks.common import dump_json, row, timeit  # noqa: E402
 from repro.core import accum_dtype_for, scan  # noqa: E402
 from repro.core.autotune import resolve_method  # noqa: E402
+from repro.utils.cache import enable_compile_cache  # noqa: E402
 from repro.core.primitives import (compress, radix_sort, split,  # noqa: E402
                                    top_p_sample)
 
@@ -112,72 +117,47 @@ def fig5_batched_ratio():
                 f"scanul1_over_scanu={tl / tu:.3f}")
 
 
-_MC_SUB = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import sys, time, functools
-import numpy as np, jax, jax.numpy as jnp
-sys.path.insert(0, {src!r})
-from repro.core import mcscan
-from repro.utils.compat import make_mesh
-mesh = make_mesh((8,), ("data",))
-for spec in {specs!r}:
-    n, s, dt = spec
-    dtype = jnp.int8 if dt == "int8" else (jnp.bfloat16 if dt == "bf16" else jnp.float32)
-    if dt == "int8":
-        x = jnp.asarray(np.random.default_rng(0).integers(-3, 4, (1, n)), dtype)
-    else:
-        x = jnp.asarray(np.random.default_rng(0).standard_normal((1, n)), dtype)
-    fn = jax.jit(lambda a: mcscan(a, mesh, "data", tile_s=s))
-    out = fn(x); jax.block_until_ready(out)
-    ts = []
-    for _ in range(5):
-        t0 = time.perf_counter(); jax.block_until_ready(fn(x))
-        ts.append(time.perf_counter() - t0)
-    t = float(np.median(ts))
-    nbytes = x.dtype.itemsize * n + out.dtype.itemsize * n
-    print(f"MC,{{n}},{{s}},{{dt}},{{t}},{{nbytes}}")
-# copy baseline
-for n in sorted(set(sp[0] for sp in {specs!r})):
-    x = jnp.asarray(np.random.default_rng(0).standard_normal((1, n)), jnp.float32)
-    fn = jax.jit(lambda a: a + 0.0)
-    jax.block_until_ready(fn(x))
-    ts = []
-    for _ in range(5):
-        t0 = time.perf_counter(); jax.block_until_ready(fn(x))
-        ts.append(time.perf_counter() - t0)
-    print(f"COPY,{{n}},0,f32,{{float(np.median(ts))}},{{8 * n}}")
-"""
+def _skip_multi_device(section: str) -> bool:
+    """Print why a multi-device section is skipped; True when it must be."""
+    n = len(jax.devices())
+    if n >= 2:
+        return False
+    print(f"# {section}: skipped — needs >= 2 devices, JAX sees {n} "
+          "(on a CPU host set XLA_FLAGS=--xla_force_host_platform_device_"
+          "count=8 on this command)", flush=True)
+    return True
 
 
 def fig8_fig9_mcscan(lens):
     """Paper Figs. 8/9: multi-device MCScan bandwidth + int8 vs fp16 elems/s.
 
-    Needs >1 device, so runs in a subprocess with 8 host devices.
+    Runs in this process on a mesh of every device JAX sees (needs >= 2).
     """
+    if _skip_multi_device("fig8"):
+        return
+    from repro.core import mcscan
+    from repro.utils.compat import make_mesh
+    mesh = make_mesh((len(jax.devices()),), ("data",))
     specs = [(n, s, "f32") for n in lens for s in (32, 64, 128)]
     specs += [(lens[-1], 128, "bf16"), (lens[-1], 128, "int8")]
-    code = _MC_SUB.format(src=os.path.abspath(
-        os.path.join(os.path.dirname(__file__), "..", "src")), specs=specs)
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                       capture_output=True, text=True, env=env, timeout=1200)
-    if r.returncode != 0:
-        row("fig8/mcscan/ERROR", 0.0, r.stderr.strip()[-120:].replace(",", ";"))
-        return
+    rng = np.random.default_rng(0)
     elems = {}
-    for line in r.stdout.splitlines():
-        parts = line.strip().split(",")
-        if parts[0] == "MC":
-            n, s, dt, t, nb = int(parts[1]), int(parts[2]), parts[3], \
-                float(parts[4]), int(parts[5])
-            row(f"fig8/mcscan/n={n}/s={s}/{dt}", t,
-                f"GB/s={nb / t / 1e9:.2f};GElems/s={n / t / 1e9:.3f}")
-            elems[dt] = n / t / 1e9
-        elif parts[0] == "COPY":
-            n, t, nb = int(parts[1]), float(parts[4]), int(parts[5])
-            row(f"fig8/copy/n={n}", t, f"GB/s={nb / t / 1e9:.2f}")
+    for n, s, dt in specs:
+        if dt == "int8":
+            x = jnp.asarray(rng.integers(-3, 4, (1, n)), jnp.int8)
+        else:
+            x = jnp.asarray(rng.standard_normal((1, n)),
+                            jnp.bfloat16 if dt == "bf16" else jnp.float32)
+        fn = jax.jit(lambda a, s=s: mcscan(a, mesh, "data", tile_s=s))
+        t = timeit(fn, x)
+        nb = x.dtype.itemsize * n + jnp.dtype(accum_dtype_for(x.dtype)).itemsize * n
+        row(f"fig8/mcscan/n={n}/s={s}/{dt}", t,
+            f"GB/s={nb / t / 1e9:.2f};GElems/s={n / t / 1e9:.3f}")
+        elems[dt] = n / t / 1e9
+    for n in sorted(set(sp[0] for sp in specs)):
+        x = jnp.asarray(rng.standard_normal((1, n)), jnp.float32)
+        t = timeit(jax.jit(lambda a: a + 0.0), x)
+        row(f"fig8/copy/n={n}", t, f"GB/s={8 * n / t / 1e9:.2f}")
     if "int8" in elems and "bf16" in elems:
         row("fig9/int8_vs_fp16", 0.0,
             f"int8_GElems/s={elems['int8']:.3f};fp16_GElems/s={elems['bf16']:.3f};"
@@ -689,60 +669,37 @@ def serve_sweep(smoke=False):
             f"continuous_speedup={dt_d / dt:.2f}x")
 
 
-_DIST_SUB = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import sys, time
-import numpy as np, jax, jax.numpy as jnp
-sys.path.insert(0, {src!r})
-from repro.analysis.roofline import summarize_collectives
-from repro.core import (dist_linear_scan, dist_radix_sort, dist_segment_scan,
-                        dist_top_p_sample)
-from repro.utils.compat import make_mesh
-rng = np.random.default_rng(0)
-for op, d, n, bpp in {specs!r}:
-    mesh = make_mesh((d,), ("data",))
+def _dist_case(op, mesh, n, bpp, rng):
+    """``(fn, args, dtype name)`` for one distributed operator row."""
+    from repro.core import (dist_linear_scan, dist_radix_sort,
+                            dist_segment_scan, dist_top_p_sample)
     if op == "dist_sort":
         x = jnp.asarray(rng.normal(size=(2, n)), jnp.bfloat16)
-        fn, args = (lambda v: dist_radix_sort(
+        return (lambda v: dist_radix_sort(
             v, mesh, "data", method="matmul", tile_s=32,
-            bits_per_pass=bpp)), (x,)
-        dt = "bfloat16"
-    elif op == "dist_top_p_sample":
+            bits_per_pass=bpp)), (x,), "bfloat16"
+    if op == "dist_top_p_sample":
         lg = jnp.asarray(rng.normal(size=(2, n)) * 3, jnp.float32)
-        fn, args = (lambda v, k: dist_top_p_sample(
+        return (lambda v, k: dist_top_p_sample(
             v, k, mesh, "data", p=0.9, method="matmul", tile_s=32,
-            bits_per_pass=bpp)), (lg, jax.random.PRNGKey(0))
-        dt = "float32"
-    elif op == "dist_linear_scan":
+            bits_per_pass=bpp)), (lg, jax.random.PRNGKey(0)), "float32"
+    if op == "dist_linear_scan":
         a = jnp.asarray(rng.uniform(0.8, 1.2, size=(2, n)), jnp.float32)
         b = jnp.asarray(rng.normal(size=(2, n)), jnp.float32)
-        fn, args = (lambda u, v: dist_linear_scan(
-            u, v, mesh, "data", method="matmul", tile_s=32)), (a, b)
-        dt = "float32"
-    else:
-        xs = jnp.asarray(rng.normal(size=(n,)), jnp.float32)
-        off = jnp.asarray([0, n // 3, n], jnp.int32)
-        fn, args = (lambda v, o: dist_segment_scan(
-            v, o, mesh, "data", method="matmul", tile_s=32)), (xs, off)
-        dt = "float32"
-    compiled = jax.jit(fn).lower(*args).compile()
-    meas = summarize_collectives(compiled.as_text())
-    jax.block_until_ready(compiled(*args))
-    ts = []
-    for _ in range(3):
-        t0 = time.perf_counter(); jax.block_until_ready(compiled(*args))
-        ts.append(time.perf_counter() - t0)
-    print(f"DIST,{{op}},{{dt}},{{d}},{{n}},{{bpp}},{{float(np.median(ts))}},"
-          f"{{meas['collective_count']}},{{meas['operand_bytes']}}")
-"""
+        return (lambda u, v: dist_linear_scan(
+            u, v, mesh, "data", method="matmul", tile_s=32)), (a, b), "float32"
+    xs = jnp.asarray(rng.normal(size=(n,)), jnp.float32)
+    off = jnp.asarray([0, n // 3, n], jnp.int32)
+    return (lambda v, o: dist_segment_scan(
+        v, o, mesh, "data", method="matmul", tile_s=32)), (xs, off), "float32"
 
 
 def dist_sweep(smoke=False):
     """Distributed operator family: measured-vs-modeled traffic -> BENCH_dist.json.
 
-    Every ``dist_*`` operator is lowered at 8 (and, non-smoke, 2) virtual
-    host devices; the post-SPMD HLO is parsed for collectives
+    Every ``dist_*`` operator is lowered at 8 (and, non-smoke, 2) devices of
+    this process — rows whose device count exceeds what JAX sees are
+    skipped with a printed line; the post-SPMD HLO is parsed for collectives
     (:func:`repro.analysis.roofline.summarize_collectives`) and compared —
     in-run, aborting on mismatch — against the closed forms of
     :func:`repro.analysis.collectives.modeled_dist_traffic`
@@ -750,29 +707,30 @@ def dist_sweep(smoke=False):
     both shape-derived, so the gate is **exact**: the committed
     ``bytes_measured`` must equal ``bytes_modeled`` on every row, and
     ``tools/compare_bench.py`` re-gates all three derived columns against the
-    committed baseline.  Timings ride along informationally (CPU backend).
+    committed baseline.  Timings ride along informationally.
     """
+    if _skip_multi_device("dist"):
+        return
     from repro.analysis.collectives import modeled_dist_traffic
+    from repro.analysis.roofline import summarize_collectives
+    from repro.utils.compat import make_mesh
     n = 256 if smoke else 2048
     specs = [("dist_sort", 8, n, 8), ("dist_top_p_sample", 8, n, 8),
              ("dist_linear_scan", 8, n, 8), ("dist_segment_scan", 8, n, 8)]
     if not smoke:
         specs += [("dist_sort", 2, n, 4), ("dist_linear_scan", 2, n, 4)]
-    code = _DIST_SUB.format(src=os.path.abspath(
-        os.path.join(os.path.dirname(__file__), "..", "src")), specs=specs)
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                       capture_output=True, text=True, env=env, timeout=1800)
-    if r.returncode != 0:
-        raise SystemExit(f"dist sweep subprocess failed:\n{r.stderr[-2000:]}")
-    for line in r.stdout.splitlines():
-        parts = line.strip().split(",")
-        if parts[0] != "DIST":
+    rng = np.random.default_rng(0)
+    for op, d, nn, bpp in specs:
+        if d > len(jax.devices()):
+            print(f"# dist/{op}/d={d}: skipped — JAX sees "
+                  f"{len(jax.devices())} devices", flush=True)
             continue
-        op, dt, d, nn, bpp, t, mc, mb = parts[1], parts[2], int(parts[3]), \
-            int(parts[4]), int(parts[5]), float(parts[6]), int(parts[7]), \
-            float(parts[8])
+        mesh = make_mesh((d,), ("data",))
+        fn, args, dt = _dist_case(op, mesh, nn, bpp, rng)
+        compiled = jax.jit(fn).lower(*args).compile()
+        meas = summarize_collectives(compiled.as_text())
+        t = timeit(compiled, *args, repeats=3, warmup=1)
+        mc, mb = meas["collective_count"], float(meas["operand_bytes"])
         mod = modeled_dist_traffic(op, d=d, n=nn, batch=1 if op ==
                                    "dist_segment_scan" else 2, dtype=dt,
                                    bits_per_pass=bpp)
@@ -844,6 +802,7 @@ def main() -> None:
     ap.add_argument("--json-out", default=None, metavar="DIR",
                     help="write BENCH_<section>.json row files to DIR")
     args = ap.parse_args()
+    enable_compile_cache()
     lens = SMOKE_LENS if args.smoke else (FULL_LENS if args.full else QUICK_LENS)
     sections = {
         "fig3": lambda: fig3_single_scan(lens),
@@ -866,8 +825,8 @@ def main() -> None:
     only = set(args.only.split(",")) if args.only else None
     if args.smoke and only is None:
         # fast sections (sort carries the pass-count guard, serve the
-        # while-loop launch guard, guards the jaxpr-identity guard, dist —
-        # the one subprocess section — the measured-vs-modeled traffic guard)
+        # while-loop launch guard, guards the jaxpr-identity guard, dist the
+        # measured-vs-modeled traffic guard)
         only = {"fig3", "fig10", "fig11", "scan_pipeline", "sort", "segscan",
                 "linrec", "precision", "ops", "serve", "dist", "guards"}
     print("name,us_per_call,derived")

@@ -9,6 +9,8 @@ import glob
 import json
 import os
 
+from repro.analysis.roofline import peaks
+
 ARCH_ORDER = ["whisper-small", "gemma2-2b", "qwen3-4b", "minicpm3-4b",
               "llama3-8b", "paligemma-3b", "zamba2-1.2b",
               "llama4-scout-17b-16e", "deepseek-moe-16b", "xlstm-350m"]
@@ -44,18 +46,16 @@ def fmt_s(x):
     return f"{x * 1e6:.0f}µs"
 
 
-HBM_BW = 819e9
-PEAK = 197e12
-
-
-def derived_terms(r):
-    """memory lower-bound term (arguments+outputs traffic — the XLA-CPU
+def derived_terms(r, device_kind: str = "TPU v5 lite"):
+    """At the peaks of ``device_kind`` (the dry runs project a v5e): memory
+    lower-bound term (arguments+outputs traffic — the XLA-CPU
     'bytes accessed' double-counts fusion-internal operands) + MFU at bound."""
     bpd = r["bytes_per_device"]
-    mem_lb = (bpd["arguments"] + bpd["output"]) / HBM_BW
+    peak = peaks(device_kind)
+    mem_lb = (bpd["arguments"] + bpd["output"]) / peak["hbm_bw"]
     ro = r["roofline"]
     step = max(ro["compute_s"], mem_lb, ro["collective_s"])
-    ideal = r["model_flops_per_chip"] / PEAK
+    ideal = r["model_flops_per_chip"] / peak["bf16_flops"]
     terms = {"compute": ro["compute_s"], "memory(lb)": mem_lb,
              "collective": ro["collective_s"]}
     return mem_lb, max(terms, key=terms.get), (ideal / step if step else 0.0)

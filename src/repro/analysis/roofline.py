@@ -1,12 +1,12 @@
-"""Three-term roofline analysis from compiled dry-run artifacts (EXPERIMENTS.md §Roofline).
+"""Three-term roofline analysis from compiled dry-run artifacts.
 
-TPU v5e per-chip constants (the TARGET hardware; this container only compiles):
-  peak bf16 compute 197 TFLOP/s · HBM 819 GB/s · ICI ~50 GB/s/link.
+Per-chip peaks come from :data:`PEAKS`, one table keyed by
+``jax.devices()[0].device_kind``; a device that is not in it is an error.
 
 ``cost_analysis()`` on the post-SPMD module is *per chip*; so
-  compute  = flops / PEAK_FLOPS
-  memory   = bytes_accessed / HBM_BW
-  collective = effective wire bytes per chip / ICI_BW
+  compute  = flops / peak bf16 FLOP/s
+  memory   = bytes_accessed / HBM bytes/s
+  collective = effective wire bytes per chip / ICI bytes/s per link
 equivalently HLO_global/(chips·peak) as in the assignment formulas.
 
 Collective bytes come from parsing the post-SPMD HLO: every
@@ -20,9 +20,22 @@ import dataclasses
 import re
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 50e9                # bytes/s / link
+# Published per-chip peaks, keyed by ``device_kind``.  TPU v5e: Google Cloud
+# documentation, "TPU v5e" — 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s of inter-chip interconnect (4 links of 50 GB/s).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes": 16e9, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The peak table row of ``device_kind``; unknown devices raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device_kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -136,10 +149,10 @@ class Roofline:
         return dataclasses.asdict(self)
 
 
-def analyze(compiled, *, chips: int = 1) -> Roofline:
+def analyze(compiled, *, device_kind: str, chips: int = 1) -> Roofline:
+    """Roofline terms of ``compiled`` at the peaks of ``device_kind``."""
+    peak = peaks(device_kind)
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x: one dict per program
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     colls = parse_collectives(compiled.as_text())
@@ -149,9 +162,9 @@ def analyze(compiled, *, chips: int = 1) -> Roofline:
     for c in colls:
         by_kind[c["kind"]] = by_kind.get(c["kind"], 0.0) + c["wire_bytes"]
     terms = {
-        "compute": flops / PEAK_FLOPS,
-        "memory": hbm / HBM_BW,
-        "collective": wire / ICI_BW,
+        "compute": flops / peak["bf16_flops"],
+        "memory": hbm / peak["hbm_bw"],
+        "collective": wire / peak["ici_bw"],
     }
     bottleneck = max(terms, key=terms.get)
     return Roofline(

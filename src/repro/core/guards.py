@@ -649,10 +649,9 @@ def _probe_family(op: str, method: str) -> str:
     return fam
 
 
-def _probe_lower(fam: str, method: str) -> None:
+def _probe_lower(fam: str, method: str, s: int = 8) -> None:
     """Lower (without compiling) a tiny instance of the family's kernel."""
     from repro.kernels import ops as kops
-    s = 8
     vec = jax.ShapeDtypeStruct((s * s,), jnp.float32)
     flg = jax.ShapeDtypeStruct((s * s,), jnp.int8)
     if fam == "linear_scan":
@@ -676,7 +675,7 @@ def _probe_lower(fam: str, method: str) -> None:
     elif fam == "top_p_sample":
         u = jax.ShapeDtypeStruct((1,), jnp.float32)
         jax.jit(lambda sp, uu: kops.topp_mask_sample_kernel(
-            sp, uu, p=0.9)).lower(vec, u)
+            sp, uu, p=0.9, s=s)).lower(vec, u)
     else:  # scan
         if method == "kernel":
             jax.jit(lambda x: kops.scan_kernel(x, s=s)).lower(vec)
@@ -704,6 +703,12 @@ def probe_lowering(op: str, method: str, *,
         True when the probe lowers (or has lowered before); False on failure
         (cached, so the attempt happens once).
 
+    Raises:
+        RuntimeError: If the probe fails to lower on the ``tpu`` backend —
+            there a kernel that does not lower is a fault, never a silent
+            fallback.  Simulated failures (:func:`force_probe_failure`)
+            still return False.
+
     Example:
         >>> probe_lowering("scan", "kernel", backend=jax.default_backend())
         True
@@ -720,9 +725,17 @@ def probe_lowering(op: str, method: str, *,
         return _PROBE_CACHE[key]
     _PROBE_CACHE[key] = True  # recursion guard: a re-entrant probe passes
     try:
-        _probe_lower(fam, method)
+        # a TPU lowers kernels at its own lane width
+        _probe_lower(fam, method, s=128 if backend == "tpu" else 8)
         ok = True
-    except Exception:  # lowering errors are backend/version specific
+    except Exception as e:  # lowering errors are backend/version specific
+        if backend == "tpu":
+            # the kernels are written for this backend: a kernel it cannot
+            # lower is a fault to report, not a reason to run something else
+            del _PROBE_CACHE[key]
+            raise RuntimeError(
+                f"method={method!r} for op {op!r} does not lower on the TPU "
+                f"backend: {type(e).__name__}: {e}") from e
         ok = False
     _PROBE_CACHE[key] = ok
     return ok
@@ -745,7 +758,8 @@ def ensure_available(method: str, op: str, *,
     Called by :func:`repro.core.autotune.maybe_resolve` on every concrete
     resolution, so explicit ``method="kernel"`` calls and table-resolved
     ``"auto"`` calls degrade identically — the same script runs unmodified on
-    a backend without Pallas support.  The degradation warns **once** per
+    a backend without Pallas support.  On a TPU a real lowering failure
+    raises instead (:func:`probe_lowering`).  The degradation warns **once** per
     (backend, family, method) with :class:`ProbeFallbackWarning` and resolves
     through the tuning table's ``fallbacks`` entry (else ``"vector"``),
     extending the rule-8 warn-once taxonomy.

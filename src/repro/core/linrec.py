@@ -25,7 +25,7 @@ keeps integer-valued payloads bit-identical across methods.
   ``(a, b) ⊕ (a', b') = (a·a', a'·b + b')`` (the correctness oracle).
 * ``"kernel"`` — the fused sequential-grid Pallas kernel
   (:mod:`repro.kernels.linrec_mm`): tile scans with the running state carried
-  in SMEM (the affine ``(Π a, sum)`` pair degenerates on a sequential walk).
+  in VMEM (the affine ``(Π a, sum)`` pair degenerates on a sequential walk).
 * ``"blocked"`` — the §4 three-phase pipeline where phase 2 scans per-block
   ``(Π a, trailing affine sum)`` summaries, so multi-block inputs still read
   and write each element once.
@@ -263,7 +263,7 @@ def _broadcast_pair(a, b):
 @_register("linear_scan", "kernel")
 def _linrec_kernel(a, b, *, method, tile_s, block_tiles, accum_dtype,
                    precision="highest"):
-    """Fused sequential-grid tile kernel with the SMEM running-state carry."""
+    """Fused sequential-grid tile kernel with the VMEM running-state carry."""
     from repro.kernels import ops as _kops  # local import to avoid cycle
     a, b = _broadcast_pair(a, b)
     return _kops.linrec_kernel(a, b, s=tile_s, accum_dtype=accum_dtype,

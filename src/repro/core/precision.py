@@ -48,6 +48,7 @@ import contextlib
 import os
 from typing import List, Optional
 
+import jax
 import jax.numpy as jnp
 
 __all__ = [
@@ -261,7 +262,12 @@ def split_f16(x, axis: int):
 
 
 def _mm(a, b, acc):
-    return jnp.matmul(a, b, preferred_element_type=acc)
+    # fp32 operands contract at full fp32 precision: a TPU's default feeds
+    # them to the matrix unit as single bf16 passes, which "highest" must not
+    f32 = jnp.dtype(jnp.float32)
+    full = jnp.dtype(a.dtype) == f32 or jnp.dtype(b.dtype) == f32
+    return jnp.matmul(a, b, preferred_element_type=acc,
+                      precision=jax.lax.Precision.HIGHEST if full else None)
 
 
 def pdot(a, b, *, acc, precision: str, exact: str = "none"):
@@ -269,9 +275,10 @@ def pdot(a, b, *, acc, precision: str, exact: str = "none"):
 
     The single contraction helper behind every matmul-engine scan triangle
     (``scan_mm``, ``scan_pipeline``, ``linrec_mm``, ``segscan_mm`` and the
-    pure-jnp tile scans).  ``"highest"`` is exactly the existing
-    ``jnp.matmul(..., preferred_element_type=acc)``; the jaxpr of a
-    ``"highest"`` call is byte-identical to the pre-precision code.
+    pure-jnp tile scans).  ``"highest"`` is exactly
+    ``jnp.matmul(..., preferred_element_type=acc)``, at
+    ``Precision.HIGHEST`` when an operand is fp32 (a TPU otherwise rounds
+    fp32 operands to one bf16 pass).
 
     Operands marked ``exact`` (the 0/1 triangular constants ``U_s``/``L⁻_s``
     and their masked segmented variants) are representable in fp16/bf16

@@ -5,16 +5,19 @@ Every operator takes ``method=`` and routes through a single table:
 * ``"matmul"`` — the paper's cube-unit scan (ScanU/ScanUL1) feeding unfused
   JAX gather/scatter (default).
 * ``"vector"`` — the plain ``jnp.cumsum`` vector baseline, same surrounding ops.
-* ``"kernel"`` — the fused Pallas kernels (``repro.kernels.split_mm``): mask
-  scan, offsets and permutation in a single launch per batch row.
+* ``"kernel"`` — the fused Pallas kernels (``repro.kernels.split_mm``): the
+  bucket mask scans and offsets in one launch per batch row, the permutation
+  as one XLA scatter; the whole top-p tail in one launch.
 * ``"blocked"`` — the unfused operators running their scans on the three-phase
   blocked pipeline of paper §4 (``repro.kernels.scan_pipeline``), for large-N
   inputs where read/write-once traffic dominates.
 
 The ``"kernel"`` and ``"blocked"`` paths are bit-identical to ``"vector"`` for
-split / multi_split / compress / radix_sort / sort / topk / top_p_sample
-(mask-scan offsets are int8 -> int32 and therefore exact; the fused top-p tail
-keeps its prefix sums on the VPU cumsum).  The sort-based operators take
+split / multi_split / compress / radix_sort / sort / topk (mask-scan offsets
+are exact integer counts).  ``top_p_sample`` sorts just as exactly, but the
+fp32 prefix sums of its tail round differently per method, so a draw that
+falls within rounding of the nucleus cutoff or of the sampled threshold may
+pick a neighbouring token.  The sort-based operators take
 ``bits_per_pass`` (default 4): each radix pass is a stable ``2^k``-way
 ``multi_split`` retiring ``k`` bits, so fp32 keys sort in ``32 / k`` passes
 instead of 32 — every (method, bits_per_pass) combination stays bit-identical
@@ -739,7 +742,7 @@ def _top_p_tail_fused(sorted_p, key, *, p, method, tile_s, interpret, u=None):
         u = jax.random.uniform(key, sorted_p.shape[:-1] + (1,),
                                dtype=jnp.float32)
     return _kops.topp_mask_sample_kernel(sorted_p, u.astype(jnp.float32), p=p,
-                                         interpret=interpret)
+                                         s=tile_s, interpret=interpret)
 
 
 def top_p_sample(logits: jax.Array, key: jax.Array, p: float = 0.9,

@@ -307,7 +307,7 @@ def scan(
               baseline).
             * ``"kernel"`` — the fused sequential-grid Pallas kernel
               (``repro.kernels.scan_mm``): one launch, tiles walked in order
-              with an SMEM-carried running partial.
+              with a VMEM-carried running partial.
             * ``"blocked"`` — the three-phase multi-core pipeline of paper §4
               (``repro.kernels.scan_pipeline``): parallel per-block partial
               scans, a block-sum carry scan, and a fused carry broadcast-add,

@@ -18,8 +18,8 @@ segment boundaries — dispatched through the same ``method=`` table as
   start.  Exact for the integer mask scans the operators are built from, and
   for integer-valued floats (the repo-wide float-parity contract).
 * ``"kernel"`` — the fused sequential-grid segmented kernel
-  (:mod:`repro.kernels.segscan_mm`): boundary-flag masks folded into the
-  ``A @ U_s`` contraction in-register, carry gated in SMEM.
+  (:mod:`repro.kernels.segscan_mm`): the segmented sum as the linear
+  recurrence ``y = (1 - flag) * y_prev + x`` on the linrec tile kernel.
 * ``"blocked"`` — the §4 three-phase pipeline with a *segmented* phase-2
   carry scan, so multi-block ragged inputs still read/write each element once.
 

@@ -76,7 +76,7 @@ def seg_blocked_scan_kernel(x: jax.Array, flags: jax.Array, *, s: int = 128,
 def linrec_kernel(a: jax.Array, b: jax.Array, *, s: int = 128,
                   accum_dtype=None, interpret: bool | None = None,
                   precision: str = "highest") -> jax.Array:
-    """Fused linear-recurrence tile scan (running state carried in SMEM)."""
+    """Fused linear-recurrence tile scan (running state carried in VMEM)."""
     return linrec_scan_tiles(a, b, s=s, accum_dtype=accum_dtype,
                              interpret=interpret, precision=precision)
 
@@ -141,31 +141,23 @@ def radix_sort_enc_kernel(enc: jax.Array, *, bits: int, bits_per_pass: int = 1,
     """Stable LSB radix sort of an unsigned encoding via fused radix passes.
 
     ``enc``: (..., n) unsigned keys (see ``primitives._encode_for_sort``).
-    Returns ``(sorted_enc, permutation)``.  One ``radix_pass_multibit`` launch
-    per ``bits_per_pass``-bit digit — ``ceil(bits / bits_per_pass)`` launches
-    total (a ragged final digit just uses the remaining bits); the tail is
-    padded once with the maximum key so it stays at the end across passes.
+    Returns ``(sorted_enc, permutation)``.  One ``radix_pass_multibit`` pass
+    per ``bits_per_pass``-bit digit — ``ceil(bits / bits_per_pass)`` passes
+    total (a ragged final digit just uses the remaining bits).
     """
     *lead, n = enc.shape
     work = enc.reshape(-1, n)
-    b = work.shape[0]
-    pad = (-n) % s
-    if pad:
-        fill = jnp.full((b, pad), jnp.iinfo(enc.dtype).max, enc.dtype)
-        work = jnp.concatenate([work, fill], axis=-1)
-    perm = jnp.broadcast_to(jnp.arange(work.shape[-1], dtype=jnp.int32),
-                            work.shape)
+    perm = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), work.shape)
     for shift in range(0, bits, bits_per_pass):
         k = min(bits_per_pass, bits - shift)
         work, perm = radix_pass_multibit(work, perm, shift=shift, pass_bits=k,
                                          s=s, interpret=interpret)
-    work = work[:, :n].reshape(*lead, n)
-    perm = perm[:, :n].reshape(*lead, n)
-    return work, perm
+    return work.reshape(*lead, n), perm.reshape(*lead, n)
 
 
-@functools.partial(jax.jit, static_argnames=("p", "interpret"))
+@functools.partial(jax.jit, static_argnames=("p", "s", "interpret"))
 def topp_mask_sample_kernel(sorted_p: jax.Array, u: jax.Array, *, p: float,
+                            s: int = 128,
                             interpret: bool | None = None) -> jax.Array:
     """Fused nucleus-sampling tail: index into the sorted order, one launch."""
-    return topp_mask_sample_tiles(sorted_p, u, p=p, interpret=interpret)
+    return topp_mask_sample_tiles(sorted_p, u, p=p, s=s, interpret=interpret)

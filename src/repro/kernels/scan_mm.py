@@ -4,7 +4,7 @@ One kernel launch scans a whole (batch of) array(s): the grid is ``(batch, n_til
 and TPU executes the tile dimension sequentially on a core, which gives us exactly the
 paper's pipelined single-core loop (Alg. 1/2) — the MTE double-buffering of AscendC
 queues is performed by the Pallas pipeline from ``BlockSpec``, and the running
-``partial`` lives in SMEM scratch instead of a vector-core register.
+``partial`` lives in a one-row VMEM scratch instead of a vector-core register.
 
 Beyond-paper fusion: on Ascend the cube core writes the tile to GM and a *separate*
 vector core re-reads it to add the carry (two extra GM trips).  On TPU the MXU and VPU
@@ -12,7 +12,8 @@ share VMEM, so the carry add is fused after the matmuls — the kernel moves 2N 
 total, the theoretical minimum for scan.
 
 dtypes follow the cube unit: fp32, bf16 (fp32 accumulate), int8 (int32 accumulate —
-the paper's mask-scan specialization), int32.
+the paper's mask-scan specialization); int32 payloads run in interpret mode
+only, since Mosaic has no int32 matmul.
 """
 from __future__ import annotations
 
@@ -24,8 +25,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import guards
-from repro.core.precision import pdot
-from repro.core.scan import accum_dtype_for
+from repro.core.scan import _operand_dtype, accum_dtype_for
+from repro.kernels.tile import default_interpret, last_as_row, tile_scan
 
 __all__ = ["scan_tiles", "scan_mm_kernel", "VARIANTS"]
 
@@ -34,38 +35,15 @@ VARIANTS = ("scanul1", "scanu")
 
 
 def _kernel(x_ref, o_ref, carry_ref, *, variant: str, acc, precision: str):
-    t = pl.program_id(1)
-
-    @pl.when(t == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        carry_ref[0, 0] = jnp.zeros((), acc)
+        carry_ref[...] = jnp.zeros(carry_ref.shape, acc)
 
-    a = x_ref[0, 0]                                   # (s, s) tile in VMEM
-    s = a.shape[-1]
-    # U_s / L⁻_s are built in-register from iota comparisons (as split_mm
-    # does) instead of being streamed from HBM as constant operands on every
-    # launch — the only HBM traffic left is the tile itself.
-    ri = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
-    ci = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
-    u = (ri <= ci).astype(a.dtype)                    # U_s
-    if variant == "scanul1":
-        # Paper Eq. 1 — all three products on the MXU, C2 accumulated in place
-        # (the L0C accumulation-buffer step of Alg. 2 line 12).
-        c2 = pdot(a, u, acc=acc, precision=precision, exact="right")
-        ones = jnp.ones((s, s), dtype=a.dtype)
-        c1 = pdot(a, ones, acc=acc, precision=precision, exact="right")
-        lm = (ri > ci).astype(acc)                    # L⁻_s
-        c2 = c2 + pdot(lm, c1, acc=acc, precision=precision, exact="left")
-        local = c2
-    else:  # scanu
-        # Alg. 1: one matmul for the s row-local scans; propagation of the row
-        # partials on the VPU (log-depth cumsum; Ascend used a serial vector loop).
-        local = pdot(a, u, acc=acc, precision=precision, exact="right")
-        row_sums = local[:, -1]
-        row_prefix = jnp.cumsum(row_sums, axis=0) - row_sums
-        local = local + row_prefix[:, None]
-    out = local + carry_ref[0, 0]
-    carry_ref[0, 0] = out[-1, -1]
+    # U_s / L⁻_s are built in-register from iota comparisons instead of being
+    # streamed from HBM, so the only HBM traffic left is the tile itself.
+    out = tile_scan(x_ref[0, 0], variant=variant, acc=acc,
+                    precision=precision) + carry_ref[...]
+    carry_ref[...] = last_as_row(out)
     o_ref[0, 0] = out
 
 
@@ -84,7 +62,7 @@ def scan_mm_kernel(variant: str, acc, s: int, interpret: bool,
             ],
             out_specs=pl.BlockSpec((1, 1, s, s), lambda i, j: (i, j, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((b, nt, s, s), acc),
-            scratch_shapes=[pltpu.SMEM((1, 1), acc)],
+            scratch_shapes=[pltpu.VMEM((1, s), acc)],
             interpret=interpret,
             name=f"scan_mm_{variant}_s{s}",
         )(tiles)
@@ -99,12 +77,13 @@ def scan_tiles(x: jax.Array, *, s: int = 128, variant: str = "scanul1",
     variant = guards.validate_choice(variant, VARIANTS, name="variant",
                                      op="scan_tiles")
     s = guards.validate_positive(s, name="s", op="scan_tiles")
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = default_interpret(interpret)
     acc = jnp.dtype(accum_dtype) if accum_dtype is not None else accum_dtype_for(x.dtype)
     *lead, n = x.shape
     ell = s * s
     xb = x.reshape(-1, n) if lead else x[None]
+    if xb.dtype == jnp.bool_:
+        xb = xb.astype(_operand_dtype(xb.dtype))
     b = xb.shape[0]
     pad = (-n) % ell
     if pad:

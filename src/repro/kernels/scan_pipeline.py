@@ -11,17 +11,17 @@ phases across Ascend AI cores:
            carries.
   Phase 3  each block broadcast-adds its carry onto its partial scan.
 
-TPU mapping (one launch per phase, grid = blocks):
+TPU mapping (grid = blocks):
 
 * ``block_partial_sums`` — the phase-1 *vector recompute*: a cheap reduction
   pass over the raw input (reads N elements, writes B scalars).  Keeping it a
   separate launch is what lets the main kernel below be single-pass.
-* ``carry_scan`` — phase 2: an exclusive scan of the ``(batch, B)`` block sums
-  on the VPU (log-depth cumsum; B is tiny compared to N).
+* ``carry_scan`` — phase 2: an exclusive XLA cumsum of the ``(batch, B)``
+  block sums (B is tiny compared to N).
 * ``block_scan_carry`` — phases 1+3 *fused*: per-block matmul partial scans
-  (the ScanU/ScanUL1 tile algebra from :mod:`repro.core.scan`, generalized to
-  a rectangular ``m×s`` row-major block view) plus the carry broadcast-add in
-  the same launch.  Each element is read from HBM once and written once — on
+  (the ScanU/ScanUL1 tile algebra of :func:`repro.kernels.tile.tile_scan`,
+  walked over the block's ``m/s`` square tiles in order) plus the carry
+  broadcast-add in the same launch.  Each element is read from HBM once and written once — on
   Ascend the carry add is a separate vector-core pass over GM (two extra trips);
   on TPU the MXU and VPU share VMEM so the add fuses behind the matmuls.
 
@@ -30,11 +30,10 @@ unfused 2 reads + 2 writes per element; the paper reports 74.9% of memcpy
 bandwidth for the fused pipeline, which ``benchmarks/run.py --only
 scan_pipeline`` tracks as ``memcpy_frac``.
 
-Block algebra (paper Eq. 1 on a rectangular block): with ``A`` the ``m×s``
-row-major view of one block, ``scan(A) = A@U_s + carry_rows(A@1_s)`` where
-``carry_rows`` is the exclusive prefix of the ``m`` row sums — a VPU cumsum for
-``variant="scanu"`` (Alg. 1) or a strictly-lower-triangular ``L⁻_m`` matvec on
-the MXU for ``variant="scanul1"`` (Alg. 2).
+Block algebra (paper Eq. 1 per ``s×s`` tile): ``scan(A) = A@U_s + row
+carries``, the row carries propagated on the VPU for ``variant="scanu"``
+(Alg. 1) or from ``L⁻_s @ A`` on the MXU for ``variant="scanul1"`` (Alg. 2);
+the running carry crosses the block's tiles as a one-row value.
 
 dtype rules follow ``accum_dtype_for``: int8/bool masks accumulate in int32
 (the paper's mask-scan specialization), bf16/f16 in fp32.
@@ -46,21 +45,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.precision import pdot
 from repro.core.scan import _operand_dtype, accum_dtype_for
+from repro.kernels.tile import default_interpret, last_as_row, tile_scan
 
 __all__ = ["blocked_scan", "block_partial_sums", "carry_scan", "block_scan_carry"]
 
 
-def _default_interpret() -> bool:
-    """Interpret everywhere but TPU (one policy for all pipeline phases).
-
-    These kernels target Mosaic, and ``mcscan``'s default path must keep
-    working on CPU *and* GPU hosts, so non-TPU backends run the Pallas
-    interpreter rather than attempting a native lowering.
-    """
-    return jax.default_backend() != "tpu"
+def _row_spec(s: int):
+    """``(b, nb, 1, s)`` per-block values, one value broadcast over lanes."""
+    return pl.BlockSpec((1, 1, 1, s), lambda i, j: (i, j, 0, 0))
 
 
 # ---------------------------------------------------------------------------
@@ -69,7 +64,8 @@ def _default_interpret() -> bool:
 
 
 def _block_sums_kernel(x_ref, o_ref, *, acc):
-    o_ref[0, 0] = jnp.sum(x_ref[0, 0].astype(acc))
+    total = jnp.sum(x_ref[0, 0].astype(acc), axis=(0, 1), keepdims=True)
+    o_ref[0, 0] = jnp.broadcast_to(total, o_ref.shape[2:])
 
 
 def block_partial_sums(blocks: jax.Array, *, accum_dtype=None,
@@ -81,20 +77,20 @@ def block_partial_sums(blocks: jax.Array, *, accum_dtype=None,
     so the scheduler can overlap it with (or run it ahead of) the main scan
     launch.
     """
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = default_interpret(interpret)
     b, nb, m, s = blocks.shape
     acc = jnp.dtype(accum_dtype) if accum_dtype is not None \
         else accum_dtype_for(blocks.dtype)
-    return pl.pallas_call(
+    sums = pl.pallas_call(
         functools.partial(_block_sums_kernel, acc=acc),
         grid=(b, nb),
         in_specs=[pl.BlockSpec((1, 1, m, s), lambda i, j: (i, j, 0, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, nb), acc),
+        out_specs=_row_spec(s),
+        out_shape=jax.ShapeDtypeStruct((b, nb, 1, s), acc),
         interpret=interpret,
         name=f"scan_pipeline_block_sums_m{m}_s{s}",
     )(blocks)
+    return sums[:, :, 0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -102,30 +98,13 @@ def block_partial_sums(blocks: jax.Array, *, accum_dtype=None,
 # ---------------------------------------------------------------------------
 
 
-def _carry_scan_kernel(r_ref, o_ref):
-    row = r_ref[0, :]
-    inc = jnp.cumsum(row, axis=0)
-    o_ref[0, :] = jnp.concatenate([jnp.zeros((1,), row.dtype), inc[:-1]])
-
-
-def carry_scan(sums: jax.Array, *, interpret: bool | None = None) -> jax.Array:
+def carry_scan(sums: jax.Array) -> jax.Array:
     """Phase 2: exclusive prefix of the ``(b, nb)`` block sums, per batch row.
 
-    ``nb`` is small (N / block_len), so a single log-depth VPU cumsum per batch
-    row suffices — the analogue of the paper's phase-2 scan of ``r`` in UB.
+    ``nb`` is small (N / block_len), so one XLA cumsum over the block sums
+    suffices — the analogue of the paper's phase-2 scan of ``r`` in UB.
     """
-    if interpret is None:
-        interpret = _default_interpret()
-    b, nb = sums.shape
-    return pl.pallas_call(
-        _carry_scan_kernel,
-        grid=(b,),
-        in_specs=[pl.BlockSpec((1, nb), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, nb), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, nb), sums.dtype),
-        interpret=interpret,
-        name=f"scan_pipeline_carry_scan_nb{nb}",
-    )(sums)
+    return jnp.cumsum(sums, axis=-1) - sums
 
 
 # ---------------------------------------------------------------------------
@@ -133,36 +112,19 @@ def carry_scan(sums: jax.Array, *, interpret: bool | None = None) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def _upper_ones_in_register(s: int, dtype):
-    """``U_s`` from iota comparisons — no HBM constant operand per launch."""
-    ri = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
-    ci = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
-    return (ri <= ci).astype(dtype)
-
-
-def _block_scan_scanu_kernel(x_ref, c_ref, o_ref, *, acc, precision):
-    a = x_ref[0, 0]                                        # (m, s) block view
-    u = _upper_ones_in_register(a.shape[-1], a.dtype)
-    local = pdot(a, u, acc=acc, precision=precision, exact="right").astype(acc)
-    row_sums = local[:, -1]                                # == A @ 1_s
-    row_prefix = jnp.cumsum(row_sums, axis=0) - row_sums   # exclusive, VPU
-    o_ref[0, 0] = local + row_prefix[:, None] + c_ref[0, 0]
-
-
-def _block_scan_scanul1_kernel(x_ref, c_ref, o_ref, *, acc, precision):
-    a = x_ref[0, 0]
-    m = a.shape[0]
-    u = _upper_ones_in_register(a.shape[-1], a.dtype)
-    local = pdot(a, u, acc=acc, precision=precision, exact="right").astype(acc)
-    row_sums = local[:, -1]
-    # Paper Eq. 1 on the rectangular block: L⁻_m @ (A @ 1_s) on the MXU;
-    # L⁻_m is likewise built in-register (strict lower triangle of ones).
-    ri = jax.lax.broadcasted_iota(jnp.int32, (m, m), 0)
-    ci = jax.lax.broadcasted_iota(jnp.int32, (m, m), 1)
-    lm = (ri > ci).astype(acc)
-    row_prefix = pdot(lm, row_sums[:, None], acc=acc, precision=precision,
-                      exact="left")[:, 0]
-    o_ref[0, 0] = local + row_prefix[:, None] + c_ref[0, 0]
+def _block_scan_kernel(x_ref, c_ref, o_ref, carry_ref, *, variant, acc,
+                       precision):
+    s = x_ref.shape[-1]
+    # the running carry goes through VMEM: kept as a value, Mosaic would fold
+    # its lane broadcast into the tile add as a (1, 1) -> (s, s) broadcast,
+    # which it cannot lower
+    carry_ref[...] = c_ref[0, 0]
+    for t in range(x_ref.shape[2] // s):                 # the block's tiles
+        rows = slice(t * s, (t + 1) * s)
+        out = tile_scan(x_ref[0, 0, rows, :], variant=variant, acc=acc,
+                        precision=precision) + carry_ref[...]
+        o_ref[0, 0, rows, :] = out
+        carry_ref[...] = last_as_row(out)
 
 
 def block_scan_carry(blocks: jax.Array, carries: jax.Array, *,
@@ -171,39 +133,37 @@ def block_scan_carry(blocks: jax.Array, carries: jax.Array, *,
                      precision: str = "highest") -> jax.Array:
     """Fused phases 1+3: matmul partial scan of each block + carry add.
 
-    ``blocks``: ``(b, nb, m, s)`` row-major block views; ``carries``: ``(b,
-    nb)`` exclusive block prefixes from :func:`carry_scan`.  One grid step
-    reads its block from HBM once, runs the ScanU/ScanUL1 algebra in VMEM, adds
-    the block carry, and writes the final result once — the read/write-once
-    property the paper obtains by overlapping cube and vector units.
+    ``blocks``: ``(b, nb, m, s)`` row-major block views with ``m`` a multiple
+    of ``s``; ``carries``: ``(b, nb)`` exclusive block prefixes from
+    :func:`carry_scan`.  One grid step reads its block from HBM once, scans
+    its ``m / s`` square tiles in order with the ScanU/ScanUL1 tile algebra
+    in VMEM, starting from the block carry, and writes the final result once
+    — the read/write-once property the paper obtains by overlapping cube and
+    vector units.
     """
-    if interpret is None:
-        interpret = _default_interpret()
+    if variant not in ("scanu", "scanul1"):
+        raise ValueError(f"unknown scan variant {variant!r}")
+    interpret = default_interpret(interpret)
     b, nb, m, s = blocks.shape
     acc = jnp.dtype(accum_dtype) if accum_dtype is not None \
         else accum_dtype_for(blocks.dtype)
     block_spec = pl.BlockSpec((1, 1, m, s), lambda i, j: (i, j, 0, 0))
-    carry_spec = pl.BlockSpec((1, 1), lambda i, j: (i, j))
-    if variant == "scanul1":
-        kern = functools.partial(_block_scan_scanul1_kernel, acc=acc,
-                                 precision=precision)
-    elif variant == "scanu":
-        kern = functools.partial(_block_scan_scanu_kernel, acc=acc,
-                                 precision=precision)
-    else:
-        raise ValueError(f"unknown scan variant {variant!r}")
-    # U_s / L⁻_m are built in-register inside the kernels from iota
+    rows = jnp.broadcast_to(carries.astype(acc)[:, :, None, None],
+                            (b, nb, 1, s))
+    # U_s / L⁻_s are built in-register inside the kernel from iota
     # comparisons, so the only operands streamed from HBM are the data blocks
     # and the nb carries.
     return pl.pallas_call(
-        kern,
+        functools.partial(_block_scan_kernel, variant=variant, acc=acc,
+                          precision=precision),
         grid=(b, nb),
-        in_specs=[block_spec, carry_spec],
+        in_specs=[block_spec, _row_spec(s)],
         out_specs=block_spec,
         out_shape=jax.ShapeDtypeStruct((b, nb, m, s), acc),
+        scratch_shapes=[pltpu.VMEM((1, s), acc)],
         interpret=interpret,
         name=f"scan_pipeline_{variant}_m{m}_s{s}",
-    )(blocks, carries)
+    )(blocks, rows)
 
 
 # ---------------------------------------------------------------------------
@@ -226,8 +186,7 @@ def blocked_scan(x: jax.Array, *, s: int = 128, block_tiles: int = 8,
     """
     if variant not in ("scanu", "scanul1"):
         raise ValueError(f"unknown scan variant {variant!r}")
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = default_interpret(interpret)
     acc = jnp.dtype(accum_dtype) if accum_dtype is not None \
         else accum_dtype_for(x.dtype)
     *lead, n = x.shape
@@ -250,7 +209,7 @@ def blocked_scan(x: jax.Array, *, s: int = 128, block_tiles: int = 8,
         carries = jnp.zeros((b, 1), acc)
     else:
         sums = block_partial_sums(blocks, accum_dtype=acc, interpret=interpret)
-        carries = carry_scan(sums, interpret=interpret)
+        carries = carry_scan(sums)
     out = block_scan_carry(blocks, carries, variant=variant, accum_dtype=acc,
                            interpret=interpret, precision=precision)
     out = out.reshape(b, nb * block_len)[:, :n]

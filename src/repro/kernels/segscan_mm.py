@@ -1,201 +1,43 @@
-"""Segmented matmul scan kernels: the carry resets at segment boundaries.
+"""Segmented scan kernels: the carry resets at segment boundaries.
 
 The paper scans one flat array; packed variable-length batches (MoE group
 dispatch, continuous-batching decode, ragged data pipelines) need *segmented*
-scans — prefix sums that restart at segment starts.  Dakkak et al. show
-segmented scan is expressible on matrix engines with the same matmul
-formulation the paper uses for ScanU/ScanUL1 (see PAPERS.md), and that is what
-these kernels implement: the boundary-flag mask folds into the ``A @ U_s``
-contraction in-register, and the §4 blocked pipeline's phase-2 carry scan
-becomes a *segmented* carry scan, so multi-block ragged inputs still read and
-write each element exactly once.
+scans — prefix sums that restart at segment starts.
 
 Representation: packed values ``(n,)`` plus int8 boundary flags ``(n,)`` where
 ``flags[i] = 1`` iff element ``i`` starts a new segment (derived from CSR-style
-offsets by ``repro.core.segmented``).  Tiles/blocks are the same row-major
-``(m, s)`` views as the unsegmented kernels.
+offsets by ``repro.core.segmented``).
 
-Per-block algebra (the segmented analogue of paper Eq. 1), all built
-in-register from ``broadcasted_iota`` like the PR 3 kernels:
-
-* ``start[r, j]`` — the last flagged column ``<= j`` in row ``r`` (a ``cummax``
-  of ``iota * flag``); the row-local segmented scans are then the masked
-  contraction ``local[r, j] = sum_i A[r, i] * [start[r, j] <= i <= j]`` — the
-  flag mask folded into the ``A @ U_s`` operand (tile kernel), or equivalently
-  ``(A @ U_s)[r, j] - (A @ U_s - A)[r, start[r, j]]`` (rectangular blocked
-  kernel, which avoids materialising an ``(m, s, s)`` mask for large blocks).
-* row carries propagate under the segmented-pair operator
-  ``(a ⊕ b) = (b.flagged ? b.sum : a.sum + b.sum)``: with ``ts[r]`` the row's
-  trailing-segment sum and ``lastb[r]`` the last boundary-carrying row before
-  ``r``, the carry into row ``r`` is ``sum_{q=lastb[r]}^{r-1} ts[q]`` — again a
-  masked triangular contraction on the MXU.
-* an incoming block/tile carry is added only where no boundary has been seen
-  since the block start (``seen`` mask); the outgoing carry is simply
-  ``out[-1, -1]`` (the scan value at the block end *is* the trailing-segment
-  sum).
-
-As in ``split_mm``, the in-kernel gathers (`take_along_axis` of the row-start
-indices) are what Ascend would issue as vector-core gather instructions; the
-interpret path — the CI target — executes them exactly, and on hardware they
-require Mosaic dynamic-gather support.
-
-dtype rules follow ``accum_dtype_for``: int8/bool flags and values accumulate
-in int32 (the paper's mask-scan specialization), bf16/f16 in fp32.
-
-Every boundary-masked contraction goes through :func:`repro.core.precision.pdot`
-— the masked triangular operands stay exact 0/1 matrices under fp16/bf16, so
-``precision="compensated"``/``"fast"`` apply to segmented scans with the same
-ulp contract as the unsegmented kernels (integer mask scans stay exact
-unconditionally; only the start-column *gather* path subtracts two compensated
-products, which the ulp oracle covers).
+A segmented sum is the linear recurrence ``y_i = (1 - flags_i) * y_{i-1} +
+x_i``: the multiplier is 0 where a segment starts and 1 elsewhere.  So both
+launch shapes are the linear-recurrence kernels of
+:mod:`repro.kernels.linrec_mm` — the sequential-grid tile walk and the §4
+blocked pipeline, whose affine phase-2 carry scan is exactly the segmented
+carry scan — fed the int8 ``1 - flags`` as multipliers and run in the
+accumulation dtype (``accum_dtype_for``: int8/bool values accumulate in
+int32, bf16/f16 in fp32), so integer scans stay exact.  They do not use the
+MXU, and ``precision=`` does not change them.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import guards
-from repro.core.precision import pdot
 from repro.core.scan import _operand_dtype, accum_dtype_for
+from repro.kernels.linrec_mm import linrec_blocked_scan, linrec_scan_tiles
 
-__all__ = ["seg_scan_tiles", "seg_blocked_scan", "seg_block_summaries",
-           "seg_carry_scan", "seg_block_scan_carry"]
-
-
-def _default_interpret() -> bool:
-    """Interpret everywhere but TPU (same policy as ``scan_pipeline``)."""
-    return jax.default_backend() != "tpu"
+__all__ = ["seg_scan_tiles", "seg_blocked_scan"]
 
 
-# ---------------------------------------------------------------------------
-# In-kernel segmented block algebra (shared by the tile and blocked kernels)
-# ---------------------------------------------------------------------------
-
-
-def _row_starts(f32: jax.Array) -> jax.Array:
-    """``start[r, j]`` = last flagged column ``<= j`` in row ``r`` (0 if none).
-
-    ``f32``: (m, s) int32 flags.  Built from a ``cummax`` over
-    ``iota * flag`` — the in-register analogue of streaming a per-tile
-    boundary index vector from HBM.
-    """
-    m, s = f32.shape
-    pos = jax.lax.broadcasted_iota(jnp.int32, (m, s), 1)
-    return jax.lax.cummax(jnp.where(f32 > 0, pos, 0), axis=1)
-
-
-def _seg_rows_masked(a: jax.Array, startc: jax.Array, acc,
-                     precision: str = "highest") -> jax.Array:
-    """Row-local segmented scans via the flag-masked ``A @ U_s`` contraction.
-
-    ``mask[r, i, j] = (start[r, j] <= i <= j)`` folds the boundary flags into
-    the upper-triangular ones operand, so one batched MXU contraction yields
-    every row's segmented scan.  Used by the square tile kernel (``m == s``);
-    the rectangular blocked kernel uses :func:`_seg_rows_gather` to avoid the
-    ``(m, s, s)`` mask tensor.
-    """
-    m, s = a.shape
-    ri = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
-    cj = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
-    tri = ri <= cj                                     # U_s, in-register
-    mseg = (tri[None, :, :] & (ri[None, :, :] >= startc[:, None, :]))
-    mseg = mseg.astype(a.dtype)
-    # Batched matmul over the row dimension — the per-row masked U_s operand
-    # is still an exact 0/1 matrix, so pdot's "right" split applies per row.
-    local = pdot(a[:, None, :], mseg, acc=acc, precision=precision,
-                 exact="right")
-    return local[:, 0, :].astype(acc)
-
-
-def _seg_rows_gather(a: jax.Array, startc: jax.Array, acc,
-                     precision: str = "highest") -> jax.Array:
-    """Row-local segmented scans via ``A @ U_s`` + a start-column gather.
-
-    ``local_seg[r, j] = (A @ U_s)[r, j] - exclusive(A @ U_s)[r, start[r, j]]``
-    — exact for the integer/mask dtypes (and integer-valued floats) the
-    operators feed it, and O(m·s) scratch instead of the O(m·s²) mask of
-    :func:`_seg_rows_masked`; this is what the rectangular blocked kernel
-    uses.
-    """
-    s = a.shape[-1]
-    ri = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
-    cj = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
-    u = (ri <= cj).astype(a.dtype)                     # U_s, in-register
-    full = pdot(a, u, acc=acc, precision=precision,
-                exact="right").astype(acc)
-    ex = full - a.astype(acc)                          # exclusive row scans
-    base = jnp.take_along_axis(ex, startc, axis=1)     # value before seg start
-    return full - base
-
-
-def _seg_row_carries(ts: jax.Array, hrow: jax.Array, acc,
-                     precision: str = "highest") -> jax.Array:
-    """Exclusive segmented carry over rows: ``c[r] = sum ts[lastb[r] .. r-1]``.
-
-    ``ts``: (m,) per-row trailing-segment sums; ``hrow``: (m,) bool
-    row-has-boundary.  ``lastb[r]`` is the last boundary-carrying row strictly
-    before ``r`` (0 if none) — rows before it belong to earlier segments and
-    must not leak in.  The sum is one masked triangular contraction on the
-    MXU (the ScanUL1 ``L⁻`` product with the segment mask folded in).
-    """
-    m = ts.shape[0]
-    rowi = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)[:, 0]
-    lastb_inc = jax.lax.cummax(jnp.where(hrow, rowi, 0), axis=0)
-    lastb_ex = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), lastb_inc[:-1]])
-    qi = jax.lax.broadcasted_iota(jnp.int32, (m, m), 0)
-    rj = jax.lax.broadcasted_iota(jnp.int32, (m, m), 1)
-    m2 = ((qi < rj) & (qi >= lastb_ex[None, :])).astype(acc)
-    return pdot(ts[None, :], m2, acc=acc, precision=precision,
-                exact="right")[0]
-
-
-def _seg_block_scan(a: jax.Array, f32: jax.Array, acc, *, masked: bool,
-                    precision: str = "highest"):
-    """Segmented scan of one (m, s) row-major block held in VMEM.
-
-    Returns ``(out, seen)`` where ``out`` is the block-local segmented scan
-    (no incoming carry) and ``seen[r, j]`` is true iff a boundary occurs at or
-    before element ``(r, j)`` — the positions an incoming carry must NOT
-    touch.
-    """
-    startc = _row_starts(f32)
-    rows = _seg_rows_masked if masked else _seg_rows_gather
-    local = rows(a, startc, acc, precision)
-    ts = local[:, -1]                                  # trailing-segment sums
-    hrow = jnp.max(f32, axis=1) > 0
-    c = _seg_row_carries(ts, hrow, acc, precision)
-    seen_row = jax.lax.cummax(f32, axis=1) > 0         # boundary <= j in row
-    out = local + jnp.where(seen_row, jnp.zeros((), acc), c[:, None])
-    prev = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32),
-         jax.lax.cummax(hrow.astype(jnp.int32), axis=0)[:-1]])
-    seen = seen_row | (prev[:, None] > 0)
-    return out, seen
-
-
-# ---------------------------------------------------------------------------
-# Sequential-grid fused kernel (the segmented analogue of scan_mm)
-# ---------------------------------------------------------------------------
-
-
-def _seg_kernel(x_ref, f_ref, o_ref, carry_ref, *, acc, precision):
-    t = pl.program_id(1)
-
-    @pl.when(t == 0)
-    def _init():
-        carry_ref[0, 0] = jnp.zeros((), acc)
-
-    a = x_ref[0, 0]                                    # (s, s) tile in VMEM
-    f32 = f_ref[0, 0].astype(jnp.int32)
-    out, seen = _seg_block_scan(a, f32, acc, masked=True, precision=precision)
-    out = out + jnp.where(seen, jnp.zeros((), acc), carry_ref[0, 0])
-    carry_ref[0, 0] = out[-1, -1]                      # trailing-segment sum
-    o_ref[0, 0] = out
+def _as_recurrence(x, flags, op):
+    """``(multipliers, values, acc)`` of the segmented scan of ``x``."""
+    guards.validate_broadcastable_to(jnp.shape(flags), x.shape, op=op)
+    acc = accum_dtype_for(x.dtype)
+    if x.dtype == jnp.bool_:
+        x = x.astype(_operand_dtype(x.dtype))
+    keep = (jnp.broadcast_to(flags, x.shape) == 0).astype(jnp.int8)
+    return keep, x, acc
 
 
 def seg_scan_tiles(x: jax.Array, flags: jax.Array, *, s: int = 128,
@@ -203,159 +45,13 @@ def seg_scan_tiles(x: jax.Array, flags: jax.Array, *, s: int = 128,
                    precision: str = "highest") -> jax.Array:
     """Segmented scan of the last axis with one sequential-grid launch.
 
-    ``x``: ``(..., n)`` packed values; ``flags``: same shape, nonzero where an
-    element starts a new segment.  Tiles are walked in order with the
-    SMEM-carried running partial of ``scan_mm``; the carry is gated by the
-    in-tile ``seen`` mask so it never crosses a boundary.
+    ``x``: ``(..., n)`` packed values; ``flags``: broadcastable to it,
+    nonzero where an element starts a new segment.
     """
-    guards.validate_broadcastable_to(jnp.shape(flags), x.shape,
-                                     op="seg_scan_tiles")
-    s = guards.validate_positive(s, name="s", op="seg_scan_tiles")
-    if interpret is None:
-        interpret = _default_interpret()
-    acc = jnp.dtype(accum_dtype) if accum_dtype is not None \
-        else accum_dtype_for(x.dtype)
-    *lead, n = x.shape
-    xb = x.reshape(-1, n) if lead else x[None]
-    if xb.dtype == jnp.bool_:
-        xb = xb.astype(_operand_dtype(xb.dtype))
-    fb = jnp.broadcast_to(flags.astype(jnp.int8), x.shape).reshape(xb.shape)
-    b = xb.shape[0]
-    ell = s * s
-    pad = (-n) % ell
-    if pad:
-        xb = jnp.pad(xb, ((0, 0), (0, pad)))
-        fb = jnp.pad(fb, ((0, 0), (0, pad)))           # pad joins last segment
-    nt = xb.shape[-1] // ell
-    tiles = xb.reshape(b, nt, s, s)
-    ftiles = fb.reshape(b, nt, s, s)
-    spec = pl.BlockSpec((1, 1, s, s), lambda i, j: (i, j, 0, 0))
-    out = pl.pallas_call(
-        functools.partial(_seg_kernel, acc=acc, precision=precision),
-        grid=(b, nt),
-        in_specs=[spec, spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((b, nt, s, s), acc),
-        scratch_shapes=[pltpu.SMEM((1, 1), acc)],
-        interpret=interpret,
-        name=f"segscan_mm_s{s}",
-    )(tiles, ftiles)
-    out = out.reshape(b, nt * ell)[:, :n]
-    return out.reshape(*lead, n) if lead else out[0]
-
-
-# ---------------------------------------------------------------------------
-# Blocked pipeline (§4) with a segmented phase-2 carry scan
-# ---------------------------------------------------------------------------
-
-
-def _seg_summary_kernel(x_ref, f_ref, ts_ref, h_ref, *, acc):
-    a = x_ref[0, 0]                                    # (m, s) block view
-    f32 = f_ref[0, 0].astype(jnp.int32)
-    m, s = a.shape
-    rank = (jax.lax.broadcasted_iota(jnp.int32, (m, s), 0) * s +
-            jax.lax.broadcasted_iota(jnp.int32, (m, s), 1))
-    lastpos = jnp.max(jnp.where(f32 > 0, rank, 0))
-    trailing = jnp.where(rank >= lastpos, a.astype(acc), jnp.zeros((), acc))
-    ts_ref[0, 0] = jnp.sum(trailing)
-    h_ref[0, 0] = jnp.max(f32)
-
-
-def seg_block_summaries(blocks: jax.Array, fblocks: jax.Array, *,
-                        accum_dtype=None, interpret: bool | None = None):
-    """Phase 1 summary pass: ``(trailing sums, has-boundary)`` per block.
-
-    The unsegmented pipeline's phase 1 reduces each block to one sum; the
-    segmented pair ``(ts, h)`` is its analogue under the segmented-scan
-    operator: ``ts`` is the sum of elements after the block's last boundary
-    and ``h`` records whether the block contains any boundary.  Reads N
-    elements, writes 2·nb scalars; no dependency on the partial scans.
-    """
-    if interpret is None:
-        interpret = _default_interpret()
-    b, nb, m, s = blocks.shape
-    acc = jnp.dtype(accum_dtype) if accum_dtype is not None \
-        else accum_dtype_for(blocks.dtype)
-    spec = pl.BlockSpec((1, 1, m, s), lambda i, j: (i, j, 0, 0))
-    return pl.pallas_call(
-        functools.partial(_seg_summary_kernel, acc=acc),
-        grid=(b, nb),
-        in_specs=[spec, spec],
-        out_specs=(pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-                   pl.BlockSpec((1, 1), lambda i, j: (i, j))),
-        out_shape=(jax.ShapeDtypeStruct((b, nb), acc),
-                   jax.ShapeDtypeStruct((b, nb), jnp.int32)),
-        interpret=interpret,
-        name=f"segscan_pipeline_summaries_m{m}_s{s}",
-    )(blocks, fblocks)
-
-
-def _seg_carry_kernel(ts_ref, h_ref, o_ref, *, precision):
-    ts = ts_ref[0, :]
-    hrow = h_ref[0, :] > 0
-    o_ref[0, :] = _seg_row_carries(ts, hrow, ts.dtype, precision)
-
-
-def seg_carry_scan(sums: jax.Array, has_boundary: jax.Array, *,
-                   interpret: bool | None = None,
-                   precision: str = "highest") -> jax.Array:
-    """Phase 2: exclusive *segmented* scan of the block summaries.
-
-    This is the tentpole change to the §4 pipeline: the plain exclusive cumsum
-    of block sums becomes a scan under the segmented-pair operator
-    ``(a ⊕ b) = (b.h ? b.ts : a.ts + b.ts)`` — a carry never crosses a block
-    that contains a boundary.  ``nb`` is tiny, so one masked triangular
-    contraction per batch row suffices.
-    """
-    if interpret is None:
-        interpret = _default_interpret()
-    b, nb = sums.shape
-    return pl.pallas_call(
-        functools.partial(_seg_carry_kernel, precision=precision),
-        grid=(b,),
-        in_specs=[pl.BlockSpec((1, nb), lambda i: (i, 0)),
-                  pl.BlockSpec((1, nb), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, nb), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, nb), sums.dtype),
-        interpret=interpret,
-        name=f"segscan_pipeline_carry_nb{nb}",
-    )(sums, has_boundary)
-
-
-def _seg_block_carry_kernel(x_ref, f_ref, c_ref, o_ref, *, acc, precision):
-    a = x_ref[0, 0]
-    f32 = f_ref[0, 0].astype(jnp.int32)
-    out, seen = _seg_block_scan(a, f32, acc, masked=False, precision=precision)
-    o_ref[0, 0] = out + jnp.where(seen, jnp.zeros((), acc), c_ref[0, 0])
-
-
-def seg_block_scan_carry(blocks: jax.Array, fblocks: jax.Array,
-                         carries: jax.Array, *, accum_dtype=None,
-                         interpret: bool | None = None,
-                         precision: str = "highest") -> jax.Array:
-    """Fused phases 1+3: block-local segmented scan + gated carry add.
-
-    Each grid step reads its block once, runs the segmented block algebra in
-    VMEM, adds the block carry only where no boundary has been seen since the
-    block start, and writes the result once — the §4 read/write-once property
-    extended to ragged inputs.
-    """
-    if interpret is None:
-        interpret = _default_interpret()
-    b, nb, m, s = blocks.shape
-    acc = jnp.dtype(accum_dtype) if accum_dtype is not None \
-        else accum_dtype_for(blocks.dtype)
-    spec = pl.BlockSpec((1, 1, m, s), lambda i, j: (i, j, 0, 0))
-    return pl.pallas_call(
-        functools.partial(_seg_block_carry_kernel, acc=acc,
-                          precision=precision),
-        grid=(b, nb),
-        in_specs=[spec, spec, pl.BlockSpec((1, 1), lambda i, j: (i, j))],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((b, nb, m, s), acc),
-        interpret=interpret,
-        name=f"segscan_pipeline_m{m}_s{s}",
-    )(blocks, fblocks, carries)
+    del precision
+    keep, x, acc = _as_recurrence(x, flags, "seg_scan_tiles")
+    return linrec_scan_tiles(keep, x, s=s, accum_dtype=accum_dtype or acc,
+                             interpret=interpret, name="segscan_mm")
 
 
 def seg_blocked_scan(x: jax.Array, flags: jax.Array, *, s: int = 128,
@@ -364,45 +60,13 @@ def seg_blocked_scan(x: jax.Array, flags: jax.Array, *, s: int = 128,
                      precision: str = "highest") -> jax.Array:
     """Segmented scan of the last axis with the three-phase blocked pipeline.
 
-    Same decomposition as ``scan_pipeline.blocked_scan``: phase 1 computes
-    per-block ``(trailing sum, has-boundary)`` summaries, phase 2 runs the
-    *segmented* carry scan over them, and fused phases 1+3 produce the final
-    segmented scan with each element read and written once.
+    Phase 1 reduces each block to its affine summary (is there a boundary,
+    trailing-segment sum), phase 2 scans the summaries (the segmented carry
+    scan) and fused phases 1+3 produce the final segmented scan with each
+    element read and written once.
     """
-    guards.validate_broadcastable_to(jnp.shape(flags), x.shape,
-                                     op="seg_blocked_scan")
-    s = guards.validate_positive(s, name="s", op="seg_blocked_scan")
-    block_tiles = guards.validate_positive(block_tiles, name="block_tiles",
-                                           op="seg_blocked_scan")
-    if interpret is None:
-        interpret = _default_interpret()
-    acc = jnp.dtype(accum_dtype) if accum_dtype is not None \
-        else accum_dtype_for(x.dtype)
-    *lead, n = x.shape
-    xb = x.reshape(-1, n) if lead else x[None]
-    if xb.dtype == jnp.bool_:
-        xb = xb.astype(_operand_dtype(xb.dtype))
-    fb = jnp.broadcast_to(flags.astype(jnp.int8), x.shape).reshape(xb.shape)
-    b = xb.shape[0]
-    ell = s * s
-    t = max(1, min(block_tiles, -(-n // ell)))
-    m = t * s
-    block_len = m * s
-    pad = (-n) % block_len
-    if pad:
-        xb = jnp.pad(xb, ((0, 0), (0, pad)))
-        fb = jnp.pad(fb, ((0, 0), (0, pad)))
-    nb = xb.shape[-1] // block_len
-    blocks = xb.reshape(b, nb, m, s)
-    fblocks = fb.reshape(b, nb, m, s)
-    if nb == 1:
-        carries = jnp.zeros((b, 1), acc)
-    else:
-        sums, h = seg_block_summaries(blocks, fblocks, accum_dtype=acc,
-                                      interpret=interpret)
-        carries = seg_carry_scan(sums, h, interpret=interpret,
-                                 precision=precision)
-    out = seg_block_scan_carry(blocks, fblocks, carries, accum_dtype=acc,
-                               interpret=interpret, precision=precision)
-    out = out.reshape(b, nb * block_len)[:, :n]
-    return out.reshape(*lead, n) if lead else out[0]
+    del precision
+    keep, x, acc = _as_recurrence(x, flags, "seg_blocked_scan")
+    return linrec_blocked_scan(keep, x, s=s, block_tiles=block_tiles,
+                               accum_dtype=accum_dtype or acc,
+                               interpret=interpret, name="segscan_pipeline")

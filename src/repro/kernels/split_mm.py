@@ -2,41 +2,33 @@
 
 The paper's SplitInd is the building block of compress / radix sort / top-k /
 top-p: an int8 mask scan produces destination offsets, then values (and their
-indices) are permuted.  The pure-JAX path in ``repro.core.primitives`` runs the
-mask scan and the scatter as separate XLA ops, so the scanned mask round-trips
-through HBM between them.  Each kernel here performs the whole operator in one
-launch per batch row:
+indices) are permuted.
 
-* ``split_tiles``   — SplitInd: the int8 -> int32 mask scan runs on the MXU
-  (``A @ U_s`` with ``U_s`` materialised in-register from iota comparisons, so
-  no constant operand is streamed from HBM), destination offsets are computed
-  on the VPU, and values + original indices are scattered — mask, offsets and
-  destinations all stay in VMEM.
-* ``multi_split_tiles`` — radix-2^k generalization of SplitInd: a stable
-  ``R``-way bucket partition from one launch.  The ``(rows, R, s)`` int8
-  one-hot digit matrix is built in-register and all ``R`` bucket mask scans
-  run as a single batched ``A @ U_s`` MXU contraction; per-bucket bases come
-  from a tiny ``R``-wide scan of the bucket totals.  This is the same
-  matmul-scan trick Dakkak et al. use for TCU scans, applied to the paper's
-  binary SplitInd so one radix pass retires ``k = log2(R)`` bits.
-* ``radix_pass_multibit`` — one radix-2^k pass: k-bit digit extraction, the
-  multi-way matmul split and the permutation of (keys, permutation) in a
-  single launch; ``ceil(bits / k)`` of these sort a ``bits``-bit key.
-  ``pass_bits=1`` *is* the paper's binary LSB pass (a 2-bucket split).
+* ``bucket_ranks`` — the mask-scan half of a stable ``R``-way split in one
+  launch per batch row: for every bucket the one-hot digit tile is scanned on
+  the MXU (``A @ U_s`` for the row-local counts, ``L⁻_s @ A`` for the rows
+  above, both built in-register from iota comparisons, so no constant operand
+  is streamed from HBM), and the running per-bucket counts cross tiles in
+  VMEM.  It emits each element's rank inside its bucket and the bucket
+  totals.  ``R = 2`` is the paper's binary SplitInd; ``R = 2^k`` retires
+  ``k`` radix bits per pass (the matmul-scan trick Dakkak et al. use for TCU
+  scans, applied per bucket).
+* ``split_tiles`` / ``multi_split_tiles`` / ``radix_pass_multibit`` — the
+  operators on top: destinations are ``bucket base + rank``, and the
+  permutation of the payloads is one XLA scatter.  Ascend performs the
+  permutation with vector-core scatter instructions; Mosaic lowers no dynamic
+  scatter across a row, so on a TPU it stays outside the kernel.
 * ``topp_mask_sample_tiles`` — the tail of nucleus sampling fused: prefix sum
   of the sorted probabilities, the ``cum - p > threshold`` cutoff, the masked
-  CDF and the inverse-transform sample, emitting only one int32 per row.
+  CDF and the inverse-transform sample, emitting only one int32 per row.  Both
+  prefix sums are matmul tile scans; they round differently from XLA's
+  ``cumsum``, so the sample equals ``method="vector"``'s except where a
+  probability sits within rounding of the cutoff or of the drawn threshold.
 
-Ascend performs the post-scan permutation with vector-core gather/scatter
-instructions; the analogue here is a jnp scatter inside the kernel.  That is
-exact (integer destinations) and is what the interpret path — the CI target —
-executes; on hardware it requires Mosaic dynamic-scatter support.  The top-p
-kernel keeps its two prefix sums on the VPU (``jnp.cumsum``) so its output is
-bit-identical to the unfused ``method="vector"`` reference; the MXU tile-scan
-variant of the same prefix sum lives in ``scan_mm``.
-
-dtype rule (paper's mask-scan specialization): the mask is fed to the MXU as
-int8 and accumulated in int32, whatever the payload dtype.
+Destinations are exact integers, so every split, sort and top-k here is
+bit-identical to ``method="vector"``.  dtype rule (paper's mask-scan
+specialization): the one-hot masks feed the MXU as bf16 0/1 values with fp32
+accumulation, exact for the counts of any row below 2^24 elements.
 """
 from __future__ import annotations
 
@@ -45,87 +37,94 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import guards
+from repro.kernels.tile import (default_interpret, last_as_row, tile_scan,
+                                triangle)
 
-__all__ = ["split_tiles", "multi_split_tiles", "radix_pass_multibit",
-           "topp_mask_sample_tiles"]
+__all__ = ["bucket_ranks", "split_tiles", "multi_split_tiles",
+           "radix_pass_multibit", "topp_mask_sample_tiles"]
 
 
 # ---------------------------------------------------------------------------
-# Shared in-kernel SplitInd body
+# Shared in-kernel SplitInd body: per-bucket mask scans -> ranks and totals
 # ---------------------------------------------------------------------------
 
 
-def _splitind_body(flags_row, payload_rows, *, s: int):
-    """SplitInd on one (1, n) row held in VMEM.
+def _ranks_kernel(d_ref, rank_ref, cnt_ref, seen_ref, *, radix: int):
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        seen_ref[...] = jnp.zeros(seen_ref.shape, jnp.int32)
 
-    ``flags_row``: (1, n) values in {0, 1} (padding must be 0 — it then maps to
-    the identity at the tail).  Returns (scattered payloads, original-index
-    permutation, number of flagged elements).
+    d = d_ref[0]                                       # (s, s) digit tile
+    s = d.shape[-1]
+    upper = triangle(s, jnp.bfloat16, "upper")         # U_s
+    lower = triangle(s, jnp.bfloat16, "strict_lower")  # L⁻_s
+
+    def bucket(r, rank):
+        hit = d == r
+        oh = hit.astype(jnp.bfloat16)
+        # inclusive count of bucket r up to each element: row-local counts
+        # plus the lane sum of the counts in the rows above (exact in fp32)
+        local = jnp.dot(oh, upper, preferred_element_type=jnp.float32)
+        above = jnp.dot(lower, oh, preferred_element_type=jnp.float32)
+        inc = (local + jnp.sum(above, axis=1, keepdims=True)).astype(jnp.int32)
+        seen = seen_ref[pl.ds(r, 1), :]                # (1, s) earlier tiles
+        seen_ref[pl.ds(r, 1), :] = seen + last_as_row(inc)
+        return jnp.where(hit, inc - 1 + seen, rank)
+
+    rank_ref[0] = jax.lax.fori_loop(0, radix, bucket,
+                                    jnp.zeros(d.shape, jnp.int32))
+    cnt_ref[0] = seen_ref[...]
+
+
+def bucket_ranks(digits: jax.Array, *, radix: int, s: int = 128,
+                 interpret: bool | None = None, name: str = "bucket_ranks"):
+    """Rank of every element inside its bucket, and the bucket totals.
+
+    ``digits``: ``(b, n)`` int32 bucket ids in ``[0, radix)``.  Returns
+    ``(rank, counts)``: ``rank`` int32 ``(b, n)`` counts the earlier elements
+    of the same row and bucket; ``counts`` int32 ``(b, radix)``.  The row is
+    padded to whole ``s×s`` tiles with bucket ``radix - 1`` (removed from its
+    count again), so padding ranks after every real element.
     """
-    n = flags_row.shape[-1]
-    rows = n // s
-    # --- int8 mask scan on the MXU (ScanU rows of width s) ---
-    a = flags_row.reshape(rows, s).astype(jnp.int8)
-    ri = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
-    ci = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
-    u = (ri <= ci).astype(jnp.int8)                    # U_s, built in-register
-    local = jax.lax.dot_general(a, u, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.int32)
-    sums = local[:, -1:]
-    prefix = jnp.cumsum(sums, axis=0) - sums           # VPU carry propagation
-    inc = (local + prefix).reshape(1, n)               # inclusive mask scan
-    # --- destination offsets (paper's SplitInd indexing) ---
-    fi = flags_row.astype(jnp.int32)
-    ex = inc - fi                                      # exclusive mask scan
-    n_true = inc[0, -1]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
-    dest = jnp.where(fi == 1, ex, n_true + iota - ex)[0]
-    # --- permutation (Ascend: vector-core scatter; here: in-VMEM jnp scatter) ---
-    outs = tuple(jnp.zeros_like(p).at[0, dest].set(p[0]) for p in payload_rows)
-    ind = jnp.zeros((1, n), jnp.int32).at[0, dest].set(iota[0])
-    return outs, ind, n_true
+    interpret = default_interpret(interpret)
+    b, n = digits.shape
+    ell = s * s
+    pad = (-n) % ell
+    d = digits.astype(jnp.int32)
+    if pad:
+        d = jnp.pad(d, ((0, 0), (0, pad)), constant_values=radix - 1)
+    nt = d.shape[-1] // ell
+    rank, cnt = pl.pallas_call(
+        functools.partial(_ranks_kernel, radix=radix),
+        grid=(b, nt),
+        in_specs=[pl.BlockSpec((1, s, s), lambda i, j: (i, j, 0))],
+        out_specs=[pl.BlockSpec((1, s, s), lambda i, j: (i, j, 0)),
+                   pl.BlockSpec((1, radix, s), lambda i, j: (i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((b, nt * s, s), jnp.int32),
+                   jax.ShapeDtypeStruct((b, radix, s), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((radix, s), jnp.int32)],
+        interpret=interpret,
+        name=f"{name}_r{radix}_s{s}",
+    )(d.reshape(b, nt * s, s))
+    counts = cnt[:, :, 0]
+    if pad:
+        counts = counts.at[:, radix - 1].add(-pad)
+    return rank.reshape(b, nt * ell)[:, :n], counts
 
 
-def _multisplit_body(digits_row, payload_rows, *, s: int, radix: int,
-                     with_ind: bool = True):
-    """Stable ``radix``-way split of one (1, n) row held in VMEM.
-
-    ``digits_row``: (1, n) int32 bucket ids in ``[0, radix)``; padding must
-    carry the maximum digit ``radix - 1`` so it lands (stably) at the tail.
-    Returns (scattered payloads, original-index permutation or ``None``,
-    per-bucket totals of shape (radix,)).
-    """
-    n = digits_row.shape[-1]
-    rows = n // s
-    d = digits_row.reshape(rows, 1, s)
-    # --- (rows, R, s) one-hot digit matrix, built in-register ---
-    bid = jax.lax.broadcasted_iota(jnp.int32, (rows, radix, s), 1)
-    oh = (d == bid).astype(jnp.int8)
-    # --- all R bucket mask scans as ONE batched A @ U_s MXU contraction ---
-    ri = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
-    ci = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
-    u = (ri <= ci).astype(jnp.int8)                    # U_s, in-register
-    local = jax.lax.dot_general(oh, u, (((2,), (0,)), ((), ())),
-                                preferred_element_type=jnp.int32)
-    sums = local[:, :, -1]                             # (rows, R) block totals
-    prefix = jnp.cumsum(sums, axis=0) - sums           # VPU carry propagation
-    inc = local + prefix[:, :, None]                   # inclusive bucket scans
-    # --- per-bucket exclusive offsets (tiny R-wide scan of bucket totals) ---
-    oh32 = oh.astype(jnp.int32)
-    ex = inc - oh32                                    # exclusive within bucket
-    totals = inc[-1, :, -1]                            # (R,) bucket counts
-    base = jnp.cumsum(totals) - totals                 # exclusive bucket bases
-    # dest_i = base[d_i] + ex[d_i, i]; the one-hot contraction keeps it on the VPU
-    dest = jnp.sum(oh32 * (ex + base[None, :, None]), axis=1).reshape(n)
-    # --- permutation (Ascend: vector-core scatter; here: in-VMEM jnp scatter) ---
-    outs = tuple(jnp.zeros_like(p).at[0, dest].set(p[0]) for p in payload_rows)
-    ind = None
-    if with_ind:
-        iota = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
-        ind = jnp.zeros((1, n), jnp.int32).at[0, dest].set(iota[0])
-    return outs, ind, totals
+def _group(payloads, digits, *, radix: int, s: int, interpret, with_ind: bool,
+           name: str):
+    """Stable ``radix``-way split of ``(b, n)`` rows: destinations from
+    :func:`bucket_ranks`, then one XLA scatter of every payload."""
+    from repro.core.primitives import _scatter_payloads, _take_along_last
+    rank, counts = bucket_ranks(digits, radix=radix, s=s, interpret=interpret,
+                                name=name)
+    base = jnp.cumsum(counts, axis=-1) - counts        # exclusive bucket bases
+    dest = rank + _take_along_last(base, digits)
+    return _scatter_payloads(payloads, dest, with_indices=with_ind), counts
 
 
 # ---------------------------------------------------------------------------
@@ -133,57 +132,22 @@ def _multisplit_body(digits_row, payload_rows, *, s: int, radix: int,
 # ---------------------------------------------------------------------------
 
 
-def _split_kernel(x_ref, f_ref, z_ref, ind_ref, cnt_ref, *, s: int):
-    (z,), ind, n_true = _splitind_body(f_ref[...], (x_ref[...],), s=s)
-    z_ref[...] = z
-    ind_ref[...] = ind
-    cnt_ref[0, 0] = n_true
-
-
 def split_tiles(x: jax.Array, flags: jax.Array, *, s: int = 128,
                 interpret: bool | None = None):
-    """Fused SplitInd over the last axis: ``(z, indices, n_true)``.
+    """SplitInd over the last axis: ``(z, indices, n_true)``.
 
-    ``x``: (..., n) payload; ``flags``: same shape, boolean/int.  One kernel
-    launch per batch row; the row (padded to a multiple of ``s``) lives in VMEM.
+    ``x``: (..., n) payload; ``flags``: same shape, boolean/int.  Flagged
+    elements go first (bucket 0), the rest after them (bucket 1), both in
+    their original order.
     """
     guards.validate_same_shape(x.shape, jnp.shape(flags), op="split_tiles")
     s = guards.validate_positive(s, name="s", op="split_tiles")
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     *lead, n = x.shape
-    xb = x.reshape(-1, n)
-    fb = flags.reshape(-1, n).astype(jnp.int8)
-    b = xb.shape[0]
-    pad = (-n) % s
-    if pad:
-        xb = jnp.pad(xb, ((0, 0), (0, pad)))
-        fb = jnp.pad(fb, ((0, 0), (0, pad)))           # pad flags 0 -> identity tail
-    np_ = xb.shape[-1]
-    z, ind, cnt = pl.pallas_call(
-        functools.partial(_split_kernel, s=s),
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, np_), lambda i: (i, 0)),
-            pl.BlockSpec((1, np_), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, np_), lambda i: (i, 0)),
-            pl.BlockSpec((1, np_), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, np_), x.dtype),
-            jax.ShapeDtypeStruct((b, np_), jnp.int32),
-            jax.ShapeDtypeStruct((b, 1), jnp.int32),
-        ],
-        interpret=interpret,
-        name=f"split_mm_s{s}",
-    )(xb, fb)
-    z = z[:, :n].reshape(*lead, n)
-    ind = ind[:, :n].reshape(*lead, n)
-    cnt = cnt[:, 0].reshape(lead) if lead else cnt[0, 0]
-    return z, ind, cnt
+    digits = 1 - (flags.reshape(-1, n) != 0).astype(jnp.int32)
+    (z, ind), cnt = _group((x.reshape(-1, n),), digits, radix=2, s=s,
+                           interpret=interpret, with_ind=True, name="split")
+    n_true = cnt[:, 0].reshape(lead) if lead else cnt[0, 0]
+    return z.reshape(*lead, n), ind.reshape(*lead, n), n_true
 
 
 # ---------------------------------------------------------------------------
@@ -191,67 +155,24 @@ def split_tiles(x: jax.Array, flags: jax.Array, *, s: int = 128,
 # ---------------------------------------------------------------------------
 
 
-def _multi_split_kernel(x_ref, d_ref, z_ref, ind_ref, cnt_ref, *, s: int,
-                        radix: int):
-    (z,), ind, totals = _multisplit_body(d_ref[...], (x_ref[...],), s=s,
-                                         radix=radix)
-    z_ref[...] = z
-    ind_ref[...] = ind
-    cnt_ref[...] = totals.reshape(1, radix)
-
-
 def multi_split_tiles(x: jax.Array, digits: jax.Array, *, num_buckets: int,
                       s: int = 128, interpret: bool | None = None):
-    """Fused stable ``num_buckets``-way split: ``(z, indices, counts)``.
+    """Stable ``num_buckets``-way split: ``(z, indices, counts)``.
 
     ``x``: (..., n) payload; ``digits``: same shape, int bucket ids in
-    ``[0, num_buckets)``.  One launch per batch row; the row (padded to a
-    multiple of ``s`` with the maximum digit, so padding stays at the tail)
-    lives in VMEM.  ``counts`` has shape ``(..., num_buckets)``.
+    ``[0, num_buckets)``.  ``counts`` has shape ``(..., num_buckets)``.
     """
     guards.validate_same_shape(x.shape, jnp.shape(digits),
                                op="multi_split_tiles", b_name="digits")
     num_buckets = guards.validate_positive(num_buckets, name="num_buckets",
                                            op="multi_split_tiles")
     s = guards.validate_positive(s, name="s", op="multi_split_tiles")
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     *lead, n = x.shape
-    xb = x.reshape(-1, n)
-    db = digits.reshape(-1, n).astype(jnp.int32)
-    b = xb.shape[0]
-    pad = (-n) % s
-    if pad:
-        xb = jnp.pad(xb, ((0, 0), (0, pad)))
-        db = jnp.pad(db, ((0, 0), (0, pad)),
-                     constant_values=num_buckets - 1)  # pads sort to the tail
-    np_ = xb.shape[-1]
-    z, ind, cnt = pl.pallas_call(
-        functools.partial(_multi_split_kernel, s=s, radix=num_buckets),
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, np_), lambda i: (i, 0)),
-            pl.BlockSpec((1, np_), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, np_), lambda i: (i, 0)),
-            pl.BlockSpec((1, np_), lambda i: (i, 0)),
-            pl.BlockSpec((1, num_buckets), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, np_), x.dtype),
-            jax.ShapeDtypeStruct((b, np_), jnp.int32),
-            jax.ShapeDtypeStruct((b, num_buckets), jnp.int32),
-        ],
-        interpret=interpret,
-        name=f"multi_split_r{num_buckets}_s{s}",
-    )(xb, db)
-    if pad:
-        cnt = cnt.at[:, -1].add(-pad)                  # padding landed in bucket R-1
-    z = z[:, :n].reshape(*lead, n)
-    ind = ind[:, :n].reshape(*lead, n)
-    cnt = cnt.reshape(*lead, num_buckets)
-    return z, ind, cnt
+    (z, ind), cnt = _group((x.reshape(-1, n),), digits.reshape(-1, n),
+                           radix=num_buckets, s=s, interpret=interpret,
+                           with_ind=True, name="multi_split")
+    return (z.reshape(*lead, n), ind.reshape(*lead, n),
+            cnt.reshape(*lead, num_buckets))
 
 
 # ---------------------------------------------------------------------------
@@ -259,97 +180,30 @@ def multi_split_tiles(x: jax.Array, digits: jax.Array, *, num_buckets: int,
 # ---------------------------------------------------------------------------
 
 
-def _radix_pass_multibit_kernel(w_ref, p_ref, wo_ref, po_ref, *, shift: int,
-                                pass_bits: int, s: int):
-    w = w_ref[...]
-    mask = jnp.asarray((1 << pass_bits) - 1, w.dtype)
-    digits = ((w >> shift) & mask).astype(jnp.int32)   # k-bit digit, ascending
-    (wo, po), _, _ = _multisplit_body(digits, (w, p_ref[...]), s=s,
-                                      radix=1 << pass_bits, with_ind=False)
-    wo_ref[...] = wo
-    po_ref[...] = po
-
-
-def _radix_pass_multibit_hist_kernel(w_ref, p_ref, wo_ref, po_ref, cnt_ref, *,
-                                     shift: int, pass_bits: int, s: int):
-    w = w_ref[...]
-    mask = jnp.asarray((1 << pass_bits) - 1, w.dtype)
-    digits = ((w >> shift) & mask).astype(jnp.int32)   # k-bit digit, ascending
-    (wo, po), _, totals = _multisplit_body(digits, (w, p_ref[...]), s=s,
-                                           radix=1 << pass_bits, with_ind=False)
-    wo_ref[...] = wo
-    po_ref[...] = po
-    cnt_ref[...] = totals.reshape(1, 1 << pass_bits)
-
-
 def radix_pass_multibit(work: jax.Array, perm: jax.Array, *, shift: int,
                         pass_bits: int, s: int = 128,
                         interpret: bool | None = None,
                         with_counts: bool = False):
-    """One fused radix-2^k pass on pre-padded (b, n) operands.
+    """One radix-2^k pass on ``(b, n)`` operands: ``(work, perm)`` grouped.
 
-    ``work`` must be an unsigned encoding padded at the tail with the maximum
-    key value, so padding sorts (stably) to the end and stays there across
-    passes.  One launch retires ``pass_bits`` bits: the k-bit digit
-    extraction, the ``2^k``-way matmul split and the permutation of both
-    arrays are chained in a single launch, so ``ceil(bits / k)`` launches
-    sort the full key — a ``k``-fold cut in HBM round-trips of the (keys,
-    permutation) arrays.  ``pass_bits=1`` is exactly the paper's binary LSB
-    pass (zeros-first split on one bit).
+    ``work`` is an unsigned encoding; the ``pass_bits``-bit digit at
+    ``shift`` picks the bucket, the fused :func:`bucket_ranks` launch gives
+    the destinations and (keys, permutation) move in one scatter, so
+    ``ceil(bits / k)`` passes sort the full key.  ``pass_bits=1`` is exactly
+    the paper's binary LSB pass (zeros-first split on one bit).
 
     With ``with_counts`` the per-bucket totals of the pass — the per-shard
     digit histogram the distributed sort's bucket exchange is built from
-    (``repro.core.dist_ops``) — are exported as a third ``(b, 2^pass_bits)``
-    int32 output, straight from the in-VMEM bucket mask scans (no second
-    histogram launch).  Padding carries the maximum key, so its count lands
-    entirely in bucket ``2^pass_bits - 1``; callers that padded must subtract
-    it there (as :func:`multi_split_tiles` does).
+    (``repro.core.dist_ops``) — are returned as a third ``(b, 2^pass_bits)``
+    int32 output, straight from the same launch.
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    b, n = work.shape
     radix = 1 << pass_bits
-    if with_counts:
-        return pl.pallas_call(
-            functools.partial(_radix_pass_multibit_hist_kernel, shift=shift,
-                              pass_bits=pass_bits, s=s),
-            grid=(b,),
-            in_specs=[
-                pl.BlockSpec((1, n), lambda i: (i, 0)),
-                pl.BlockSpec((1, n), lambda i: (i, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, n), lambda i: (i, 0)),
-                pl.BlockSpec((1, n), lambda i: (i, 0)),
-                pl.BlockSpec((1, radix), lambda i: (i, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((b, n), work.dtype),
-                jax.ShapeDtypeStruct((b, n), jnp.int32),
-                jax.ShapeDtypeStruct((b, radix), jnp.int32),
-            ],
-            interpret=interpret,
-            name=f"radix_pass_multibit_hist_sh{shift}_k{pass_bits}_s{s}",
-        )(work, perm)
-    return pl.pallas_call(
-        functools.partial(_radix_pass_multibit_kernel, shift=shift,
-                          pass_bits=pass_bits, s=s),
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, n), lambda i: (i, 0)),
-            pl.BlockSpec((1, n), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, n), lambda i: (i, 0)),
-            pl.BlockSpec((1, n), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, n), work.dtype),
-            jax.ShapeDtypeStruct((b, n), jnp.int32),
-        ],
-        interpret=interpret,
-        name=f"radix_pass_multibit_sh{shift}_k{pass_bits}_s{s}",
-    )(work, perm)
+    mask = jnp.asarray(radix - 1, work.dtype)
+    digits = ((work >> shift) & mask).astype(jnp.int32)
+    (wo, po), cnt = _group((work, perm), digits, radix=radix, s=s,
+                           interpret=interpret, with_ind=False,
+                           name=f"radix_pass_sh{shift}")
+    return (wo, po, cnt) if with_counts else (wo, po)
 
 
 # ---------------------------------------------------------------------------
@@ -357,44 +211,63 @@ def radix_pass_multibit(work: jax.Array, perm: jax.Array, *, shift: int,
 # ---------------------------------------------------------------------------
 
 
-def _topp_kernel(sp_ref, u_ref, j_ref, *, p: float, n_real: int):
-    sp = sp_ref[...]                                   # (1, n) sorted probs, desc
-    cum = jnp.cumsum(sp, axis=-1)
-    cut = (cum - sp) > p                               # llama3 sample_top_p formula
-    masked = jnp.where(cut, jnp.zeros_like(sp), sp)
-    cdf = jnp.cumsum(masked, axis=-1)
-    theta = u_ref[0, 0] * cdf[0, -1]
-    j = jnp.sum((cdf < theta).astype(jnp.int32))
-    j_ref[0, 0] = jnp.clip(j, 0, n_real - 1)
+def _topp_kernel(sp_ref, u_ref, j_ref, cdf_ref, carry_ref, *, p: float,
+                 n_real: int):
+    s = sp_ref.shape[-1]
+    nt = sp_ref.shape[1] // s
+    scan = functools.partial(tile_scan, variant="scanul1", acc=jnp.float32,
+                             precision="highest")
+    zero = jnp.zeros((1, s), jnp.float32)
+    carry_ref[0:1, :] = zero                           # running cum
+    carry_ref[1:2, :] = zero                           # running masked cdf
+    for t in range(nt):
+        rows = slice(t * s, (t + 1) * s)
+        sp = sp_ref[0, rows, :]
+        cum = scan(sp) + carry_ref[0:1, :]
+        carry_ref[0:1, :] = last_as_row(cum)
+        cut = (cum - sp) > p                           # llama3 sample_top_p formula
+        cdf = scan(jnp.where(cut, jnp.zeros_like(sp), sp)) + carry_ref[1:2, :]
+        carry_ref[1:2, :] = last_as_row(cdf)
+        cdf_ref[rows, :] = cdf
+    theta = u_ref[0] * carry_ref[1:2, :]               # (1, s): u * total
+    j = jnp.zeros((1, 1), jnp.int32)
+    for t in range(nt):
+        below = cdf_ref[t * s:(t + 1) * s, :] < theta
+        j = j + jnp.sum(below.astype(jnp.int32), axis=(0, 1), keepdims=True)
+    j_ref[0] = jnp.broadcast_to(jnp.clip(j, 0, n_real - 1), (1, s))
 
 
 def topp_mask_sample_tiles(sorted_p: jax.Array, u: jax.Array, *, p: float,
+                           s: int = 128,
                            interpret: bool | None = None) -> jax.Array:
     """Fused nucleus-sampling tail.
 
     ``sorted_p``: (..., n) probabilities sorted descending; ``u``: (..., 1)
     uniform draws.  Returns the (...,) int32 index *into the sorted order* —
-    four elementwise/scan passes and a reduction in one launch, with only one
-    scalar per row leaving VMEM.  Both prefix sums use the VPU cumsum so the
-    result is bit-identical to the unfused ``method="vector"`` sampler.
+    two matmul prefix sums, the cutoff, the mask and the count in one launch
+    per row, with only one int32 per row leaving VMEM.  Rows are padded with
+    zero probabilities to whole ``s×s`` tiles.
     """
     guards.validate_probability(p, op="topp_mask_sample_tiles")
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = default_interpret(interpret)
     *lead, n = sorted_p.shape
     sp = sorted_p.reshape(-1, n).astype(jnp.float32)
-    ub = u.reshape(-1, 1).astype(jnp.float32)
     b = sp.shape[0]
+    pad = (-n) % (s * s)
+    if pad:
+        sp = jnp.pad(sp, ((0, 0), (0, pad)))
+    rows = sp.shape[-1] // s
+    ub = jnp.broadcast_to(u.reshape(-1, 1, 1).astype(jnp.float32), (b, 1, s))
     j = pl.pallas_call(
         functools.partial(_topp_kernel, p=p, n_real=n),
         grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, n), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int32),
+        in_specs=[pl.BlockSpec((1, rows, s), lambda i: (i, 0, 0)),
+                  pl.BlockSpec((1, 1, s), lambda i: (i, 0, 0))],
+        out_specs=pl.BlockSpec((1, 1, s), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, s), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((rows, s), jnp.float32),
+                        pltpu.VMEM((2, s), jnp.float32)],
         interpret=interpret,
         name="topp_mask_sample",
-    )(sp, ub)
-    return j[:, 0].reshape(lead) if lead else j[0, 0]
+    )(sp.reshape(b, rows, s), ub)
+    return j[:, 0, 0].reshape(lead) if lead else j[0, 0, 0]

@@ -177,7 +177,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *, debug=False,
     compiled = lowered.compile()
     t2 = time.time()
     mem = compiled.memory_analysis()
-    roof = rl.analyze(compiled, chips=chips)
+    # the dry run projects the v5e it was written for
+    roof = rl.analyze(compiled, device_kind="TPU v5 lite", chips=chips)
     shape = SHAPES[shape_name]
     tokens = shape.global_batch * (shape.seq_len if shape.kind != "decode" else 1)
     mflops = rl.model_flops(n_params, tokens, shape.kind)

@@ -14,6 +14,7 @@ import numpy as np
 from repro.models.model import ARCHS, build_model, get_config, synth_batch
 from repro.configs.base import ShapeConfig
 from repro.serving.engine import ServeEngine
+from repro.utils.cache import enable_compile_cache
 
 
 def main():
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--sampler", choices=["topp_scan", "topp_xla", "greedy"],
                     default="topp_scan")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)

@@ -19,6 +19,7 @@ from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.models.model import ARCHS, get_config
 from repro.training.optimizer import AdamWConfig
 from repro.training.trainer import Trainer
+from repro.utils.cache import enable_compile_cache
 
 
 def main():
@@ -39,6 +40,7 @@ def main():
                     help="override width (e.g. ~100M example model)")
     ap.add_argument("--layers", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     over = {}

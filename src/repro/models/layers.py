@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import contextlib
+import math
 import threading
 from typing import Optional
 
@@ -37,13 +38,52 @@ def linear_init(key, d_in, d_out, *, dtype=jnp.float32, scale=None):
     return {"w": ninit(key, (d_in, d_out), scale, dtype)}
 
 
+# Inside ``fenced_rows()`` (a decode step) row-wise ops (matmuls, norms) run
+# on one structure whatever the batch.  XLA compiles a one-row op unlike a
+# batched one -- a TPU turns a one-row matmul into a VPU multiply-reduce
+# instead of an MXU matmul and lays a one-row array out differently, so its
+# reductions group the features differently; a CPU uses a matrix-vector
+# kernel -- and it fuses neighbouring elementwise ops into the matmuls of
+# different batches differently.  Each rounds differently.  Fenced, the rows
+# are flattened, padded to a multiple of the TPU's 8-row sublane tile and
+# wrapped in optimization barriers (which keep XLA from slicing the padding
+# off or fusing across): a decode row then gets the same bits alone as in a
+# batch -- what makes a continuously batched request replay its solo stream
+# (dispatch rule 11).  Training and prefill take the plain path.
+ROW_TILE = 8
+
+
+@contextlib.contextmanager
+def fenced_rows():
+    """Trace the row-wise ops inside on the fenced decode structure."""
+    prev = getattr(_DT, "fenced", False)
+    _DT.fenced = True
+    try:
+        yield
+    finally:
+        _DT.fenced = prev
+
+
+def _row_padded(fn, x):
+    """``fn(x)`` for a row-wise ``fn`` over the last axis of ``x``; inside
+    :func:`fenced_rows` the flattened rows are padded to a multiple of
+    :data:`ROW_TILE` and fenced."""
+    if not getattr(_DT, "fenced", False):
+        return fn(x)
+    rows = math.prod(x.shape[:-1])
+    barrier = jax.lax.optimization_barrier
+    y = barrier(fn(barrier(jnp.pad(x.reshape(rows, x.shape[-1]),
+                                   ((0, -rows % ROW_TILE), (0, 0))))))
+    return y[:rows].reshape(*x.shape[:-1], y.shape[-1])
+
+
 def linear(p, x, cdt=None):
     # No f32 materialisation of the output: the TPU MXU accumulates bf16 matmuls
     # in f32 internally regardless, and a materialised f32 result DOUBLES the wire
     # bytes of every tensor-parallel all-reduce placed on it (§Perf I2).
     cdt = cdt or compute_dtype()
     w = p["w"].astype(cdt)
-    return jnp.matmul(x.astype(cdt), w)
+    return _row_padded(lambda v: jnp.matmul(v.astype(cdt), w), x)
 
 
 def rmsnorm_init(d, dtype=jnp.float32):
@@ -51,10 +91,12 @@ def rmsnorm_init(d, dtype=jnp.float32):
 
 
 def rmsnorm(p, x, eps=1e-6):
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    out = xf * jax.lax.rsqrt(var + eps) * (1.0 + p["g"].astype(jnp.float32))
-    return out.astype(x.dtype)
+    def norm(v):
+        vf = v.astype(jnp.float32)
+        var = jnp.mean(vf * vf, axis=-1, keepdims=True)
+        g = 1.0 + p["g"].astype(jnp.float32)
+        return (vf * jax.lax.rsqrt(var + eps) * g).astype(v.dtype)
+    return _row_padded(norm, x)
 
 
 def softcap(x, cap: Optional[float]):
@@ -75,8 +117,8 @@ def embed_lookup(p, tokens, cdt=None):
 def unembed(p, x, cdt=None):
     cdt = cdt or compute_dtype()
     w = p["embed"].astype(cdt)
-    return jnp.matmul(x.astype(cdt), w.T,
-                      preferred_element_type=jnp.float32)
+    return _row_padded(lambda v: jnp.matmul(
+        v.astype(cdt), w.T, preferred_element_type=jnp.float32), x)
 
 
 ACTS = {"silu": jax.nn.silu, "gelu": lambda x: jax.nn.gelu(x, approximate=True),

@@ -22,8 +22,8 @@ from repro.models import attention as att
 from repro.models import mamba as mmb
 from repro.models import moe as moe_lib
 from repro.models import xlstm as xl
-from repro.models.layers import (embed_init, embed_lookup, mlp,
-                                 mlp_init, rmsnorm, rmsnorm_init,
+from repro.models.layers import (embed_init, embed_lookup, fenced_rows,
+                                 mlp, mlp_init, rmsnorm, rmsnorm_init,
                                  sinusoidal_pos, softcap, unembed,
                                  use_compute_dtype)
 from repro.utils.sharding import constrain
@@ -516,8 +516,9 @@ class TransformerLM:
     def decode_step(self, params, tokens, caches, pos):
         """tokens: (B,1) int32; pos: scalar int32 write position, or per-row
         (B,) int32 for attention-only models (continuous batching)."""
-        logits, caches = self._run(params, {"tokens": tokens}, mode="decode",
-                                   caches=caches, pos=pos)
+        with fenced_rows():       # a row's bits must not depend on the batch
+            logits, caches = self._run(params, {"tokens": tokens},
+                                       mode="decode", caches=caches, pos=pos)
         return logits[:, -1], caches
 
     # ---- decode-cache specs for dry-runs ----

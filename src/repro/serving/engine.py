@@ -22,8 +22,29 @@ from repro.core import guards
 from repro.core.dist_ops import dist_top_p_sample
 from repro.core.primitives import METHODS, top_p_sample
 from repro.core.segmented import SegmentedBatch, segment_top_p_sample
+from repro.models.layers import ROW_TILE
 from repro.models.model import build_model
 from repro.utils.sharding import use_mesh
+
+
+def sample_padded_rows(logits, u, *, p, temperature, method, sort_method,
+                       bits_per_pass):
+    """Top-p sample each row of ``logits`` (B, V) with its uniform ``u``
+    (B, 1), on the rows padded to a multiple of ``layers.ROW_TILE``.
+
+    The padding gives a row the same device layout, so the same rounding of
+    its softmax and prefix sums, whether it is sampled alone or in a batch:
+    a one-row array is laid out differently from a batch of rows on a TPU,
+    and its reductions group the vocabulary differently.  Padded rows are
+    zero logits and are dropped.
+    """
+    b = logits.shape[0]
+    pad = -b % ROW_TILE
+    tok = top_p_sample(jnp.pad(logits, ((0, pad), (0, 0))), None, p=p,
+                       temperature=temperature, method=method,
+                       sort_method=sort_method, bits_per_pass=bits_per_pass,
+                       u=jnp.pad(u, ((0, pad), (0, 0))))
+    return tok[:b].astype(jnp.int32)
 
 
 class ServeEngine:
@@ -97,11 +118,12 @@ class ServeEngine:
                     logits, key, self.mesh, "model", p=self.top_p,
                     temperature=self.temperature, method="matmul",
                     bits_per_pass=self.bits_per_pass).astype(jnp.int32)
-            return top_p_sample(logits, key, p=self.top_p,
-                                temperature=self.temperature, method="matmul",
-                                sort_method="radix",
-                                bits_per_pass=self.bits_per_pass
-                                ).astype(jnp.int32)
+            u = jax.random.uniform(key, logits.shape[:-1] + (1,),
+                                   jnp.float32)
+            return sample_padded_rows(logits, u, p=self.top_p,
+                                      temperature=self.temperature,
+                                      method="matmul", sort_method="radix",
+                                      bits_per_pass=self.bits_per_pass)
         if self.sampler == "topp_segmented":
             b, v = logits.shape
             offsets = jnp.arange(b + 1, dtype=jnp.int32) * v
@@ -111,11 +133,12 @@ class ServeEngine:
                 bits_per_pass=self.bits_per_pass).astype(jnp.int32)
         method = {"topp_kernel": "kernel", "topp_blocked": "blocked",
                   "topp_auto": "auto"}.get(self.sampler, "matmul")
-        sort_method = "xla" if self.sampler == "topp_xla" else "radix"
-        return top_p_sample(logits, key, p=self.top_p,
-                            temperature=self.temperature, method=method,
-                            sort_method=sort_method,
-                            bits_per_pass=self.bits_per_pass).astype(jnp.int32)
+        u = jax.random.uniform(key, logits.shape[:-1] + (1,), jnp.float32)
+        return sample_padded_rows(
+            logits, u, p=self.top_p, temperature=self.temperature,
+            method=method,
+            sort_method="xla" if self.sampler == "topp_xla" else "radix",
+            bits_per_pass=self.bits_per_pass)
 
     def sample_packed(self, packed: SegmentedBatch, key) -> jnp.ndarray:
         """Top-p sample every segment of a packed ragged logits batch at once.

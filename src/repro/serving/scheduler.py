@@ -22,9 +22,11 @@ emits the byte-for-byte token stream :class:`~repro.serving.engine.
 ServeEngine` ``generate`` emits for it alone, given the same sampler, the
 same per-request PRNG key, and a dense ``max_len`` equal to this engine's
 ``n_blocks * page_size`` (equal attention length — rule 11).  This works
-because batch rows are computationally independent, the paged gather
-reproduces the dense cache view bitwise, and each row carries its own PRNG
-chain split exactly like the solo loop (``key, k = split(key)`` per token).
+because batch rows are computationally independent (decode runs its row-wise
+ops fenced, ``layers.fenced_rows``, and the sampler pads its rows, so a row
+rounds the same alone and in a batch), the paged gather reproduces the dense
+cache view bitwise, and each row carries its own PRNG chain split exactly
+like the solo loop (``key, k = split(key)`` per token).
 
 Time is virtual: the clock advances one unit per decode iteration, so
 arrival traces, latencies, and the whole schedule replay deterministically.
@@ -40,9 +42,9 @@ import numpy as np
 
 from repro.core import guards
 from repro.core.dist_ops import dist_top_p_sample
-from repro.core.primitives import top_p_sample
 from repro.models.model import build_model
 from repro.serving import paged_kv
+from repro.serving.engine import sample_padded_rows
 from repro.utils.sharding import use_mesh
 
 
@@ -191,35 +193,27 @@ class ContinuousEngine:
     def _sample_rows(self, logits, keys):
         """Sample one token per row, row ``r`` from ``keys[r]``.
 
-        Each row runs the single-request sampler under ``vmap`` — bitwise
-        what a solo ``ServeEngine._sample`` computes on that row with that
-        key, which is what makes continuous streams replay solo ones.
+        The per-row PRNG chains enter through the sampler's ``u=``
+        override: one uniform per row from that row's key — the same single
+        draw (identical bits) a solo ``ServeEngine._sample`` takes from it —
+        then one batched call on the same padded rows the solo sampler
+        uses, which is what makes continuous streams replay solo ones.
         """
         if self.sampler == "greedy":
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        u = jax.vmap(lambda k: jax.random.uniform(k, (1,), jnp.float32))(keys)
         if (self.sampler == "topp_sharded" and self.mesh is not None
                 and "model" in self.mesh.shape
                 and self.mesh.shape["model"] > 1):
-            # shard_map does not vmap, so the per-row PRNG chains enter
-            # through the sampler's u= override: one uniform per row from
-            # that row's key — the same single draw (identical bits) a solo
-            # ServeEngine sampler takes from it — then one batched
-            # distributed call
-            u = jax.vmap(
-                lambda k: jax.random.uniform(k, (1,), jnp.float32))(keys)
             return dist_top_p_sample(
                 logits, None, self.mesh, "model", p=self.top_p,
                 temperature=self.temperature, method="matmul",
                 bits_per_pass=self.bits_per_pass, u=u).astype(jnp.int32)
-        sort_method = "xla" if self.sampler == "topp_xla" else "radix"
-
-        def one(lg, k):
-            return top_p_sample(lg[None], k, p=self.top_p,
-                                temperature=self.temperature, method="matmul",
-                                sort_method=sort_method,
-                                bits_per_pass=self.bits_per_pass)[0]
-
-        return jax.vmap(one)(logits, keys).astype(jnp.int32)
+        return sample_padded_rows(
+            logits, u, p=self.top_p, temperature=self.temperature,
+            method="matmul",
+            sort_method="xla" if self.sampler == "topp_xla" else "radix",
+            bits_per_pass=self.bits_per_pass)
 
     # ---- prefill (one request alone, batch 1) ----
     def _prefill_impl(self, params, tokens, key, cache_len):

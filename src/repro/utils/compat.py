@@ -1,65 +1,35 @@
-"""Compatibility shims across the jax release range we support.
+"""Mesh and ``shard_map`` helpers shared by the distributed code.
 
-The repo targets current jax, but CI (and minimal environments) may run the
-0.4.x series, where ``jax.sharding.AxisType`` / ``Mesh(axis_types=...)`` and
-the top-level ``jax.shard_map`` don't exist yet.  Everything that needs one of
-those goes through here.
+Written for the installed jax (0.9): ``jax.shard_map``, ``jax.lax.axis_size``
+and meshes with explicit ``AxisType.Auto`` axes.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5: explicit/auto axis types on meshes
-    from jax.sharding import AxisType  # noqa: F401
-    _HAVE_AXIS_TYPES = True
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
-    _HAVE_AXIS_TYPES = False
-
-try:  # jax >= 0.5: shard_map graduated to the top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size
 
 
 def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with per-op replication checking off.
+    """``shard_map`` with replication (vma) checking off.
 
-    ``pallas_call`` has no replication rule (any jax we support), so bodies
-    that launch Pallas kernels — e.g. ``mcscan``'s fused blocked pipeline or
-    the Pallas-method distributed operators in ``repro.core.dist_ops`` —
-    must disable the check.  The kwarg was renamed ``check_rep`` ->
-    ``check_vma`` across jax releases; try both.
-
-    Warn path: with checking off, jax no longer *verifies* that values under
-    replicated ``out_specs`` are actually identical across shards — on newer
-    jax the first call may emit a ``UserWarning`` about unchecked replication
-    instead of a hard error.  That trade is deliberate and safe here: every
-    unchecked body in this repo only ever returns (a) per-shard outputs under
-    sharded specs or (b) values produced by ``psum``/``all_gather``, which
-    are replicated by construction; the multi-device parity suites
-    (``tests/test_distributed.py``, ``tests/test_dist_ops.py``) verify the
-    gathered results against the single-device siblings, which would catch
-    any divergence such a check would have.
+    ``pallas_call`` has no replication rule, so bodies that launch Pallas
+    kernels — e.g. ``mcscan``'s fused blocked pipeline or the Pallas-method
+    distributed operators in ``repro.core.dist_ops`` — must disable the
+    check.  With checking off, jax no longer *verifies* that values under
+    replicated ``out_specs`` are identical across shards.  That trade is safe
+    here: every unchecked body in this repo only ever returns (a) per-shard
+    outputs under sharded specs or (b) values produced by ``psum`` /
+    ``all_gather``, which are replicated by construction; the multi-device
+    parity suites (``tests/test_distributed.py``, ``tests/test_dist_ops.py``)
+    verify the gathered results against the single-device siblings.
     """
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_rep=False)
-    except TypeError:  # pragma: no cover - depends on installed jax
-        return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_vma=False)
-
-
-def axis_size(axis_name):
-    """Static size of a named mesh axis, inside ``shard_map``."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    import jax.core as _core  # 0.4.x: axis_frame(name) returns the int size
-    return _core.axis_frame(axis_name)
+    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=False)
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the installed jax has them."""
-    if _HAVE_AXIS_TYPES:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

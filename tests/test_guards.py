@@ -205,6 +205,23 @@ def test_forced_probe_failure_through_public_entry():
     assert int(cnt) == int(cntr)
 
 
+def test_real_lowering_failure_raises_on_tpu_and_degrades_elsewhere(
+        monkeypatch):
+    def refuse(fam, method, s=8):
+        raise NotImplementedError("refused by the kernel compiler")
+
+    monkeypatch.setattr(guards, "_probe_lower", refuse)
+    guards._reset_probes_for_testing()
+    try:
+        with pytest.raises(RuntimeError, match="does not lower on the TPU"):
+            guards.probe_lowering("linear_scan", "kernel", backend="tpu")
+        assert ("tpu", "linear_scan", "kernel") not in guards._PROBE_CACHE
+        assert not guards.probe_lowering("linear_scan", "kernel",
+                                         backend="gpu")
+    finally:
+        guards._reset_probes_for_testing()
+
+
 def test_probe_bypassed_under_guards_disabled():
     with guards.force_probe_failure():
         with guards.guards_disabled():

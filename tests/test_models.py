@@ -93,3 +93,23 @@ def test_local_window_masks_gemma2():
     toks2 = toks.at[0, 0].set((toks[0, 0] + 7) % 200)
     pert = np.asarray(m.forward(params, {"tokens": toks2}), np.float32)
     assert not np.allclose(base[0, -1], pert[0, -1])
+
+
+@pytest.mark.parametrize("batch", [4, 8, 16])
+def test_decode_row_bits_do_not_depend_on_batch(batch):
+    """A decode row computes the same bits alone and in a batch (decode
+    steps run their row-wise ops fenced: ``layers.fenced_rows``) — what lets
+    a continuously batched request replay its solo stream (rule 11)."""
+    cfg = get_config("qwen3-4b", smoke=True)
+    m = build_model(cfg)
+    params = m.init(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    c1 = jax.tree.map(lambda c: jnp.asarray(rng.standard_normal(c.shape),
+                                            c.dtype), m.empty_caches(1, 16))
+    cb = jax.tree.map(lambda c: jnp.repeat(c, batch, axis=-4), c1)
+    dec = jax.jit(m.decode_step)
+    solo, _ = dec(params, jnp.asarray([[5]], jnp.int32), c1, jnp.int32(7))
+    rows, _ = dec(params, jnp.full((batch, 1), 5, jnp.int32), cb,
+                  jnp.full((batch,), 7, jnp.int32))
+    for r in range(batch):
+        np.testing.assert_array_equal(np.asarray(rows[r]), np.asarray(solo[0]))

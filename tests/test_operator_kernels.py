@@ -1,8 +1,9 @@
 """Fused operator kernels (split_mm) vs the unfused paths.
 
 Acceptance: ``method="kernel"`` is bit-identical to ``method="vector"`` for
-split / radix_sort / topk / top_p_sample on CPU interpret mode, across
-fp32 / bf16 / int8 payloads and odd lengths (non-multiples of s²).
+split / radix_sort / topk on CPU interpret mode, across fp32 / bf16 / int8
+payloads and odd lengths (non-multiples of s²); top_p_sample draws the same
+tokens on these inputs, where no draw sits within rounding of a threshold.
 """
 import numpy as np
 import jax

@@ -7,7 +7,7 @@ The contract under test (docs/architecture.md dispatch rule 9 +
   env > call-site argument; explicit ``method="vector"`` + explicit
   non-default precision raises; auto/override/env landing on vector silently
   degrades to ``"highest"`` (the vector path *is* the fp32 reference);
-* ``precision="highest"`` traces byte-identically to the pre-precision code;
+* ``precision="highest"`` traces exactly like a ``Precision.HIGHEST`` matmul;
 * the Ozaki split is exact (``x == ldexp(hi + ldexp(lo, -SPLIT_SHIFT), e)``
   whenever the per-slice dynamic range fits the ~22-bit window);
 * measured max ulp at the conditioning scale stays under
@@ -125,7 +125,7 @@ def test_env_precision_changes_resolution_pre_trace(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# highest is the identity: pdot traces exactly like jnp.matmul
+# highest is the identity: pdot traces exactly like a HIGHEST jnp.matmul
 # ---------------------------------------------------------------------------
 
 
@@ -141,7 +141,8 @@ def test_pdot_highest_is_plain_matmul():
                                         precision="highest", exact=exact),
                       a, b) == \
             _jaxpr(lambda u, v: jnp.matmul(
-                u, v, preferred_element_type=jnp.float32), a, b)
+                u, v, preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST), a, b)
 
 
 def test_pdot_non_f32_data_falls_through():
@@ -391,7 +392,10 @@ def test_linrec_grad_runs_under_compensated():
 
 
 if HAS_HYPOTHESIS:
-    finite_f32 = st.floats(min_value=-1e30, max_value=1e30, width=32,
+    # bounds written as the float32 values they stand for: the installed
+    # hypothesis rejects width=32 bounds that float32 cannot represent
+    F32_1E30 = float(np.float32(1e30))
+    finite_f32 = st.floats(min_value=-F32_1E30, max_value=F32_1E30, width=32,
                            allow_nan=False, allow_infinity=False,
                            allow_subnormal=True)
 
@@ -422,7 +426,7 @@ if HAS_HYPOTHESIS:
                                       precision=precision, tile_s=8))
 
     @given(hi=hnp.arrays(np.float32, 64,
-                         elements=st.floats(-1e30, 1e30, width=32,
+                         elements=st.floats(-F32_1E30, F32_1E30, width=32,
                                             allow_subnormal=True)))
     @settings(deadline=None)
     def test_hyp_split_window(hi):

@@ -229,3 +229,18 @@ def test_page_budget_guard_fires_under_checks():
                           bad_pos, jnp.zeros((b, 2), jnp.uint32),
                           jnp.zeros((b,), bool), jnp.ones((b,), jnp.int32),
                           jnp.full((b,), -1, jnp.int32), 2)
+
+
+def test_padded_sampler_row_matches_its_batch():
+    """``sample_padded_rows`` draws a row's token the same alone and among
+    other rows (both engines sample through it: rule 11)."""
+    from repro.serving.engine import sample_padded_rows
+    rng = np.random.default_rng(3)
+    logits = jnp.asarray(rng.standard_normal((5, 300)) * 3.0, jnp.float32)
+    u = jnp.asarray(rng.random((5, 1)), jnp.float32)
+    kw = dict(p=0.9, temperature=1.0, method="matmul", sort_method="radix",
+              bits_per_pass=4)
+    batch = np.asarray(sample_padded_rows(logits, u, **kw))
+    for r in range(5):
+        alone = sample_padded_rows(logits[r:r + 1], u[r:r + 1], **kw)
+        assert int(alone[0]) == int(batch[r])
