@@ -111,37 +111,38 @@ def _block_apply(p, h, cfg, kind: str, *, positions=None, mode="train",
         return h + y, new_cache, aux
 
     # ---- attention sub-block ----
-    hin = rmsnorm(p["norm1"], h, cfg.norm_eps)
-    full_cache = cache
-    if kind == "dec" and cache is not None:
-        cache = cache["kv"]          # self-attn part of the enc-dec cache
-    if kind == "mla":
-        if mode == "decode":
-            y, new_cache = att.mla_decode(p["attn"], hin, cfg, cache, pos)
-        elif mode == "prefill":
-            y, new_cache = att.mla_full(p["attn"], hin, cfg, positions=positions,
-                                        return_cache=True, cache_len=cache_len)
+    with jax.named_scope("attention"):
+        hin = rmsnorm(p["norm1"], h, cfg.norm_eps)
+        full_cache = cache
+        if kind == "dec" and cache is not None:
+            cache = cache["kv"]          # self-attn part of the enc-dec cache
+        if kind == "mla":
+            if mode == "decode":
+                y, new_cache = att.mla_decode(p["attn"], hin, cfg, cache, pos)
+            elif mode == "prefill":
+                y, new_cache = att.mla_full(p["attn"], hin, cfg, positions=positions,
+                                            return_cache=True, cache_len=cache_len)
+            else:
+                y = att.mla_full(p["attn"], hin, cfg, positions=positions)
         else:
-            y = att.mla_full(p["attn"], hin, cfg, positions=positions)
-    else:
-        if mode == "decode":
-            # a "pages" leaf marks the paged KV layout (continuous batching);
-            # plain {"k","v"} caches stay on the dense kv_layout baseline
-            dec = (att.attn_decode_paged if (cache is not None
-                                             and "pages" in cache)
-                   else att.attn_decode)
-            y, new_cache = dec(p["attn"], hin, cfg, cache, pos,
-                               window=window)
-        elif mode == "prefill":
-            y, new_cache = att.attn_full(p["attn"], hin, cfg, positions=positions,
-                                         causal=causal, window=window,
-                                         prefix_len=prefix_len, return_cache=True,
-                                         cache_len=cache_len)
-        else:
-            y = att.attn_full(p["attn"], hin, cfg, positions=positions,
-                              causal=causal, window=window, prefix_len=prefix_len)
-    y = _maybe_post(p, "post_norm1", y, cfg)
-    h = h + y
+            if mode == "decode":
+                # a "pages" leaf marks the paged KV layout (continuous batching);
+                # plain {"k","v"} caches stay on the dense kv_layout baseline
+                dec = (att.attn_decode_paged if (cache is not None
+                                                 and "pages" in cache)
+                       else att.attn_decode)
+                y, new_cache = dec(p["attn"], hin, cfg, cache, pos,
+                                   window=window)
+            elif mode == "prefill":
+                y, new_cache = att.attn_full(p["attn"], hin, cfg, positions=positions,
+                                             causal=causal, window=window,
+                                             prefix_len=prefix_len, return_cache=True,
+                                             cache_len=cache_len)
+            else:
+                y = att.attn_full(p["attn"], hin, cfg, positions=positions,
+                                  causal=causal, window=window, prefix_len=prefix_len)
+        y = _maybe_post(p, "post_norm1", y, cfg)
+        h = h + y
 
     # ---- cross attention (whisper decoder) ----
     if kind == "dec":
@@ -161,13 +162,14 @@ def _block_apply(p, h, cfg, kind: str, *, positions=None, mode="train",
         pass
 
     # ---- mlp / moe sub-block ----
-    hin = rmsnorm(p["norm2"], h, cfg.norm_eps)
-    if kind == "moe":
-        y, aux = moe_lib.moe_apply(p["moe"], hin, cfg, no_drop=mode == "decode")
-    else:
-        y = mlp(p["mlp"], hin, act=cfg.act)
-    y = _maybe_post(p, "post_norm2", y, cfg)
-    return h + y, new_cache, aux
+    with jax.named_scope("mlp"):
+        hin = rmsnorm(p["norm2"], h, cfg.norm_eps)
+        if kind == "moe":
+            y, aux = moe_lib.moe_apply(p["moe"], hin, cfg, no_drop=mode == "decode")
+        else:
+            y = mlp(p["mlp"], hin, act=cfg.act)
+        y = _maybe_post(p, "post_norm2", y, cfg)
+        return h + y, new_cache, aux
 
 
 def _decode_cache_for(kind, cfg, h, cache_len, block_params=None, enc_out=None):
@@ -334,6 +336,7 @@ class TransformerLM:
             h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
         return h
 
+    @jax.named_scope("head")
     def _logits(self, params, h):
         cfg = self.cfg
         h = rmsnorm(params["final_norm"], h, cfg.norm_eps)

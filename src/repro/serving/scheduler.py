@@ -76,6 +76,14 @@ class RequestState:
     finish_step: Optional[int] = None
 
 
+def _span(name: str, **stats):
+    """A profiler host span ``name`` carrying ``stats`` (the request id
+    ``rid`` and the counts known at that boundary).  It costs about a
+    microsecond when no trace is running; under ``jax.profiler`` it lands in
+    the trace beside the device's events."""
+    return jax.profiler.TraceAnnotation(name, **stats)
+
+
 def count_while_loops(jaxpr) -> int:
     """Count ``while`` equations in a (closed) jaxpr, nested ones included.
 
@@ -190,6 +198,7 @@ class ContinuousEngine:
                                      static_argnums=(8,))
 
     # ---- sampling: per-row key chains, same operators as ServeEngine ----
+    @jax.named_scope("sampler")
     def _sample_rows(self, logits, keys):
         """Sample one token per row, row ``r`` from ``keys[r]``.
 
@@ -319,11 +328,20 @@ class ContinuousEngine:
         One host sync per decode tick (plus one per admission).  Replaying
         the same trace on the same engine yields the identical result dict
         (virtual-time clock, FCFS admission, lowest-page-first allocation).
+
+        Under ``jax.profiler`` the loop leaves host spans (:func:`_span`):
+        ``engine/arrive`` (an instant, on the first pass at which the step
+        clock reaches the request's arrival), ``engine/admit`` (allocation,
+        prefill and insert; a failed allocation ends it early, so a request's
+        last one is its admission) around ``engine/prefill`` and
+        ``engine/insert``, ``engine/release``, and per tick ``engine/stage``,
+        ``engine/decode`` and ``engine/harvest``.
         """
         reqs = [(self._validate(r), r) for r in requests]
         order = sorted(range(len(reqs)),
                        key=lambda i: (reqs[i][1].arrival_step, i))
         queue = [reqs[i] for i in order]
+        arrivals = [r for _, r in queue]
 
         b = self.max_batch
         # reset page tables: stale tables from a previous run must not alias
@@ -341,7 +359,15 @@ class ContinuousEngine:
         eos = np.full(b, -1, np.int32)
         step = 0
         ticks = 0
+        n_arrived = 0
         finished: List[RequestState] = []
+
+        def release(st):
+            with _span("engine/release", rid=st.request.rid,
+                       pages=len(st.page_ids)):
+                self.alloc.release(st.page_ids)
+                self.caches = paged_kv.clear_page_table(self.caches, st.slot)
+            finished.append(st)
 
         def admit(toks_np, req):
             total = toks_np.size + req.max_new_tokens
@@ -349,26 +375,28 @@ class ContinuousEngine:
             slot = next((i for i, s in enumerate(slots) if s is None), None)
             if slot is None:
                 return False
-            pages = self.alloc.alloc(m)
-            if pages is None:
-                return False
-            key = jnp.asarray(np.asarray(req.key), jnp.uint32)
-            key, k0 = jax.random.split(key)
-            t0, dense = self._prefill(self.params,
-                                      jnp.asarray(toks_np)[None, :], k0,
-                                      m * self.page_size)
-            self.caches = paged_kv.insert_request(self.caches, dense, slot,
-                                                  pages)
-            st = RequestState(request=req, slot=slot, page_ids=pages,
-                              admit_step=step, tokens=[int(t0)])
+            with _span("engine/admit", rid=req.rid, prompt=toks_np.size,
+                       pages=m):
+                pages = self.alloc.alloc(m)
+                if pages is None:
+                    return False
+                key = jnp.asarray(np.asarray(req.key), jnp.uint32)
+                key, k0 = jax.random.split(key)
+                with _span("engine/prefill", rid=req.rid):
+                    t0, dense = self._prefill(self.params,
+                                              jnp.asarray(toks_np)[None, :], k0,
+                                              m * self.page_size)
+                with _span("engine/insert", rid=req.rid):
+                    self.caches = paged_kv.insert_request(self.caches, dense,
+                                                          slot, pages)
+                st = RequestState(request=req, slot=slot, page_ids=pages,
+                                  admit_step=step, tokens=[int(t0)])
             e = -1 if req.eos_id is None else int(req.eos_id)
             fin = ((e >= 0 and st.tokens[0] == e)
                    or req.max_new_tokens <= 1)
             if fin:
                 st.finish_step = step
-                self.alloc.release(pages)
-                self.caches = paged_kv.clear_page_table(self.caches, slot)
-                finished.append(st)
+                release(st)
                 return True
             slots[slot] = st
             tok[slot] = st.tokens[0]
@@ -384,6 +412,11 @@ class ContinuousEngine:
             if ticks > max_ticks:
                 raise RuntimeError(f"run: exceeded max_ticks={max_ticks} — "
                                    "scheduler is not draining")
+            while (n_arrived < len(arrivals)
+                   and arrivals[n_arrived].arrival_step <= step):
+                with _span("engine/arrive", rid=arrivals[n_arrived].rid):
+                    pass
+                n_arrived += 1
             # strict FCFS admission of arrived requests
             while queue and queue[0][1].arrival_step <= step:
                 if not admit(*queue[0]):
@@ -395,29 +428,31 @@ class ContinuousEngine:
                 continue
 
             rem_before = rem.copy()
-            out, nsteps, tok_d, self.caches, pos_d, keys_d, done_d, rem_d = \
-                self._decode_n(self.params, self.caches, jnp.asarray(tok),
-                               jnp.asarray(pos), jnp.asarray(keys),
-                               jnp.asarray(done), jnp.asarray(rem),
-                               jnp.asarray(eos), self.tick_tokens)
-            # ONE host sync for the whole tick (np.array: device_get views
-            # can be read-only, and admission mutates these in place)
-            out, nsteps, tok, pos, keys, done, rem = [
-                np.array(x) for x in jax.device_get(
-                    (out, nsteps, tok_d, pos_d, keys_d, done_d, rem_d))]
+            rows = [r for r, st in enumerate(slots) if st is not None]
+            with _span("engine/stage"):
+                staged = [jnp.asarray(x) for x in (tok, pos, keys, done, rem, eos)]
+            with _span("engine/decode", rows=len(rows)):
+                out, nsteps, tok_d, self.caches, pos_d, keys_d, done_d, rem_d = \
+                    self._decode_n(self.params, self.caches, *staged,
+                                   self.tick_tokens)
+                # ONE host sync for the whole tick (np.array: device_get views
+                # can be read-only, and admission mutates these in place)
+                out, nsteps, tok, pos, keys, done, rem = [
+                    np.array(x) for x in jax.device_get(
+                        (out, nsteps, tok_d, pos_d, keys_d, done_d, rem_d))]
             base = step
             step += int(nsteps)
-            for r, st in enumerate(slots):
-                if st is None:
-                    continue
-                emitted = int(rem_before[r] - rem[r])
-                st.tokens.extend(int(t) for t in out[r, :emitted])
-                if done[r]:
-                    st.finish_step = base + emitted
-                    self.alloc.release(st.page_ids)
-                    self.caches = paged_kv.clear_page_table(self.caches, r)
-                    finished.append(st)
-                    slots[r] = None
+            emitted = {r: int(rem_before[r] - rem[r]) for r in rows}
+            with _span("engine/harvest", steps=int(nsteps),
+                       tokens=sum(emitted.values()), rows=len(rows),
+                       pages=self.alloc.in_use):
+                for r in rows:
+                    st = slots[r]
+                    st.tokens.extend(int(t) for t in out[r, :emitted[r]])
+                    if done[r]:
+                        st.finish_step = base + emitted[r]
+                        release(st)
+                        slots[r] = None
 
         finished.sort(key=lambda st: (st.finish_step, st.request.rid))
         total_tokens = sum(len(st.tokens) for st in finished)

@@ -113,3 +113,50 @@ def test_decode_row_bits_do_not_depend_on_batch(batch):
                   jnp.full((batch,), 7, jnp.int32))
     for r in range(batch):
         np.testing.assert_array_equal(np.asarray(rows[r]), np.asarray(solo[0]))
+
+
+SCOPES = ("attention", "mlp", "head", "sampler")
+
+
+def _fusion_scopes(hlo_text):
+    """(scope or None, op_name) of every fusion in an optimized HLO text that
+    carries an ``op_name``: the innermost of :data:`SCOPES` on its path."""
+    import re
+    out = []
+    for line in hlo_text.splitlines():
+        m = re.search(r' fusion\(.*op_name="([^"]*)"', line)
+        if m:
+            path = m.group(1).split("/")
+            out.append((next((p for p in reversed(path) if p in SCOPES), None),
+                        m.group(1)))
+    return out
+
+
+@pytest.mark.parametrize("arch,sampler", [("qwen3-4b", "topp_scan"),
+                                          ("qwen3-4b", "greedy"),
+                                          ("deepseek-moe-16b", "greedy")])
+def test_decode_step_fusions_carry_named_scopes(arch, sampler):
+    """The compiled decode tick names its layers through ``jax.named_scope``
+    (``attention``, ``mlp``, ``head``, ``sampler``) in each fusion's
+    ``op_name``, which is how a device trace's ops are joined to layers.
+    Inside the layer loop every fusion is scoped but the loop's own slicing
+    of the stacked weights and caches and its counter."""
+    from repro.serving.scheduler import ContinuousEngine
+    cfg = get_config(arch, smoke=True)
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    eng = ContinuousEngine(cfg, params, sampler=sampler, max_batch=2,
+                           page_size=8, n_pages=9, max_len=24, tick_tokens=4)
+    b = eng.max_batch
+    text = eng._decode_n.lower(
+        eng.params, eng.caches, jnp.zeros((b,), jnp.int32),
+        jnp.zeros((b,), jnp.int32), jnp.zeros((b, 2), jnp.uint32),
+        jnp.zeros((b,), bool), jnp.ones((b,), jnp.int32),
+        jnp.full((b,), -1, jnp.int32), 4).compile().as_text()
+    fusions = _fusion_scopes(text)
+    assert {s for s, _ in fusions} >= set(SCOPES)
+    layer_loop = "jit(_decode_n_impl)/while/body/while/body/"
+    in_layers = [(s, n) for s, n in fusions if n.startswith(layer_loop)]
+    loop_own = {"dynamic_slice", "dynamic_update_slice", "add"}
+    stray = [n for s, n in in_layers
+             if s is None and n[len(layer_loop):] not in loop_own]
+    assert in_layers and not stray, stray

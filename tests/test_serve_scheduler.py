@@ -244,3 +244,50 @@ def test_padded_sampler_row_matches_its_batch():
     for r in range(5):
         alone = sample_padded_rows(logits[r:r + 1], u[r:r + 1], **kw)
         assert int(alone[0]) == int(batch[r])
+
+
+def test_run_leaves_engine_spans_under_the_profiler(tmp_path):
+    """Under ``jax.profiler`` the scheduler leaves its ``engine/*`` host spans
+    with request ids and counts: arrivals before admissions, admissions in
+    FCFS order with prefill and insert inside, and harvest counts that add up
+    to the run's own stats."""
+    import glob
+    from jax.profiler import ProfileData
+    eng = _engine(max_batch=2, tick_tokens=2)
+    reqs = [_req("a", [1, 2, 3], 4, 0), _req("b", [4, 5], 3, 1),
+            _req("c", [6, 7, 8, 9], 5, 2, arrival=1)]
+    eng.run(reqs)                                   # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        res = eng.run(reqs)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    spans = sorted(((e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+                    for p in ProfileData.from_file(path).planes
+                    if p.name.startswith("/host:") for line in p.lines
+                    for e in line.events if e.name.startswith("engine/")),
+                   key=lambda s: s[1])
+    named = lambda n: [s for s in spans if s[0] == n]   # noqa: E731
+    assert {s[0] for s in spans} == {
+        "engine/arrive", "engine/admit", "engine/prefill", "engine/insert",
+        "engine/release", "engine/stage", "engine/decode", "engine/harvest"}
+    arrive = {s[3]["rid"]: s[1] for s in named("engine/arrive")}
+    admit = named("engine/admit")
+    assert [s[3]["rid"] for s in admit] == ["a", "b", "c"]
+    assert [(s[3]["prompt"], s[3]["pages"]) for s in admit] == [(3, 1), (2, 1), (4, 2)]
+    assert all(arrive[s[3]["rid"]] <= s[1] for s in admit)
+    for inner in ("engine/prefill", "engine/insert"):
+        got = named(inner)
+        assert [s[3]["rid"] for s in got] == ["a", "b", "c"]
+        assert all(a[1] <= s[1] and s[2] <= a[2] for a, s in zip(admit, got))
+    pages = {s[3]["rid"]: s[3]["pages"] for s in admit}
+    assert sorted((s[3]["rid"], s[3]["pages"]) for s in named("engine/release")) == \
+        sorted(pages.items())
+    harvest = named("engine/harvest")
+    stats = res["stats"]
+    assert len(harvest) == len(named("engine/decode")) == len(named("engine/stage"))
+    assert sum(s[3]["steps"] for s in harvest) == stats["steps"]
+    assert sum(s[3]["tokens"] for s in harvest) + len(admit) == stats["total_tokens"]
+    assert max(s[3]["pages"] for s in harvest) <= stats["peak_pages"]
+    assert [s[3]["rows"] for s in harvest] == [s[3]["rows"] for s in named("engine/decode")]
