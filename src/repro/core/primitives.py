@@ -107,11 +107,11 @@ def dispatch(op: str, method: str) -> Callable:
 def _take_along_last(x, idx):
     """Gather ``x`` along the last axis with indices widened to int32.
 
-    The single gather helper shared by the unfused operator paths (bucket-base
-    lookup in :func:`_multi_split_dest`) and the fused wrappers (the ordering
-    gathers in :func:`top_p_sample`): indices are cast to int32 in exactly one
-    place, so permutation composition is dtype-stable regardless of how the
-    caller produced its index array.
+    The single gather helper shared by the unfused operator paths (the sorted
+    keys of a packed radix sort in :func:`_radix_passes_unfused`) and the fused
+    wrappers (the ordering gathers in :func:`top_p_sample`): indices are cast
+    to int32 in exactly one place, so permutation composition is dtype-stable
+    regardless of how the caller produced its index array.
 
     Args:
         x: Source array ``(..., n)`` (any dtype).
@@ -278,7 +278,8 @@ def _multi_split_dest(digits, num_buckets, *, method, tile_s):
     ``(..., R, n)`` int8 one-hot digit matrix yields all ``R`` per-bucket mask
     scans at once (the multi-way analogue of the paper's binary SplitInd mask
     scan); per-bucket bases are the tiny ``R``-wide exclusive prefix of the
-    bucket counts.
+    bucket counts.  Each element's offset is its bucket base plus its row of
+    the mask scan, selected by the same one-hot matrix (no gather).
 
     Args:
         digits: Integer bucket ids ``(..., n)`` in ``[0, num_buckets)``.
@@ -292,12 +293,15 @@ def _multi_split_dest(digits, num_buckets, *, method, tile_s):
     """
     d32 = digits.astype(jnp.int32)
     buckets = jnp.arange(num_buckets, dtype=jnp.int32)
-    oh = (d32[..., None, :] == buckets[:, None]).astype(jnp.int8)  # (..., R, n)
+    hit = d32[..., None, :] == buckets[:, None]                    # (..., R, n)
+    oh = hit.astype(jnp.int8)
     ex = scan(oh, axis=-1, exclusive=True, method=method, tile_s=tile_s)
     counts = ex[..., -1] + oh[..., -1].astype(jnp.int32)           # (..., R)
     base = jnp.cumsum(counts, axis=-1) - counts                    # R-wide scan
-    ex_d = jnp.take_along_axis(ex, d32[..., None, :], axis=-2)[..., 0, :]
-    dest = _take_along_last(base, d32) + ex_d
+    # read each element's own bucket row through the one-hot digits: an exact
+    # R-way masked sum that fuses with the scan, where a gather along R and one
+    # of ``base`` would each move every element
+    dest = jnp.sum(jnp.where(hit, ex + base[..., None], 0), axis=-2)
     return dest, counts
 
 
@@ -469,23 +473,55 @@ def _encode_for_sort(x: jax.Array) -> Tuple[jax.Array, int, Callable]:
 @_register("radix_passes", "matmul", "vector", "blocked")
 def _radix_passes_unfused(enc, bits, *, method, tile_s, interpret,
                           bits_per_pass=1):
-    """``ceil(bits / k)`` multi-way splits, keys and permutation co-scattered.
+    """``ceil(bits / k)`` stable multi-way splits of the ``bits``-bit keys.
 
-    The identity permutation is materialised once (hoisted out of the pass
-    loop) and scattered *alongside* the keys through each pass's destination
-    offsets — no per-pass iota rebuild and no per-pass gather composition.
+    On a TPU every data-dependent gather or scatter over a whole row costs
+    milliseconds, however little it moves, so each pass moves its payload
+    once.  Where ``bits - k + ceil(log2 n) <= 32`` (``k`` the first pass's
+    width; 16-bit keys with ``k = 4`` up to ``n = 2^20``) the payload is one
+    ``uint32`` per element: the key's bits above its first digit, shifted
+    above the element's original index.  The first pass reads its digit from
+    ``enc``, every later one from the payload, and each moves the payload
+    once, by sorting each row on its destinations; after the last pass the
+    low bits are the permutation, and the sorted keys are one gather of
+    ``enc`` through it (dead code where the caller drops them, as
+    ``top_p_sample`` does).
+    Wider keys or longer rows (fp32/int32 keys, in general) move the keys and
+    the permutation as two payloads through each pass.  Both forms give the
+    same ``(keys, permutation)`` bit for bit.
     """
     n = enc.shape[-1]
-    perm = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), enc.shape)
-    work = enc
+    idx_bits = (n - 1).bit_length()
+    k0 = min(bits_per_pass, bits)
+    if bits - k0 + idx_bits > 32:
+        perm = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), enc.shape)
+        work = enc
+        for shift in range(0, bits, bits_per_pass):
+            k = min(bits_per_pass, bits - shift)
+            mask = jnp.asarray((1 << k) - 1, work.dtype)
+            digits = ((work >> shift) & mask).astype(jnp.int32)
+            dest, _ = _multi_split_dest(digits, 1 << k, method=method,
+                                        tile_s=tile_s)
+            work, perm = _scatter_payloads((work, perm), dest,
+                                           with_indices=False)
+        return work, perm
+    iota = jnp.arange(n, dtype=jnp.uint32)
+    packed = ((enc >> k0).astype(jnp.uint32) << idx_bits) | iota
     for shift in range(0, bits, bits_per_pass):
         k = min(bits_per_pass, bits - shift)
-        mask = jnp.asarray((1 << k) - 1, work.dtype)
-        digits = ((work >> shift) & mask).astype(jnp.int32)
+        if shift == 0:
+            digits = enc & jnp.asarray((1 << k) - 1, enc.dtype)
+        else:
+            digits = (packed >> (idx_bits + shift - k0)) & ((1 << k) - 1)
         dest, _ = _multi_split_dest(digits, 1 << k, method=method,
                                     tile_s=tile_s)
-        work, perm = _scatter_payloads((work, perm), dest, with_indices=False)
-    return work, perm
+        # out[dest[i]] = packed[i] as a sort of each row keyed by ``dest``
+        # (a permutation, so stability does not matter): on a TPU v5e it
+        # moves 8 rows of 152064 in 1.2 ms, a unique-index scatter in 5.6 ms
+        _, packed = jax.lax.sort((dest, packed), dimension=-1, num_keys=1,
+                                 is_stable=False)
+    perm = (packed & ((1 << idx_bits) - 1)).astype(jnp.int32)
+    return _take_along_last(enc, perm), perm
 
 
 @_register("radix_passes", "kernel")
@@ -507,11 +543,17 @@ def radix_sort(x: jax.Array, *, descending: bool = False, method: str = "auto",
     ``bits_per_pass``-bit digit, so the key sorts in ``ceil(bits /
     bits_per_pass)`` passes — 8 for fp32 and 4 for bf16/fp16 at the default
     ``bits_per_pass=4``, vs. 32/16 binary splits in the paper's formulation —
-    a ``bits_per_pass``-fold cut in HBM round-trips of the (keys, permutation)
-    arrays.  ``method="kernel"`` chains digit extraction, the batched matmul
-    mask scans and the permutation inside one fused ``radix_pass_multibit``
-    launch per digit.  Every (method, bits_per_pass) combination is
-    bit-identical: bucket offsets are exact int8 -> int32 mask scans.
+    a ``bits_per_pass``-fold cut in HBM round-trips of the payload.  On a TPU
+    each data-dependent movement of a row costs far more than the mask scans,
+    so where the key bits left after the first pass and the index fit one
+    ``uint32`` (``bits - bits_per_pass + ceil(log2 n) <= 32``: 16-bit keys at
+    the default up to ``n = 2^20``) the unfused methods move that one word per
+    element and pass; wider keys and longer rows move the keys and the
+    permutation apart (see :func:`_radix_passes_unfused`).
+    ``method="kernel"`` chains digit extraction, the batched matmul mask scans
+    and the permutation inside one fused ``radix_pass_multibit`` launch per
+    digit.  Every (method, bits_per_pass) combination is bit-identical: bucket
+    offsets are exact int8 -> int32 mask scans.
 
     Args:
         x: Keys ``(..., n)``; floats (fp16/bf16/fp32) are sorted via the

@@ -7,6 +7,7 @@ a ``tpu_custom_call``.  The topology is described inside a module fixture,
 never at import time.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import ops
 
 VOCAB = 151936          # qwen3-4b's vocabulary: the sampler's row width
+LOGITS_WIDTH = 152064   # qwen3-4b's padded vocabulary: the served logits' width
 
 
 @pytest.fixture(scope="module")
@@ -96,3 +98,32 @@ def test_one_row_projection_stays_a_matmul_on_v5e(width, one_chip):
     hlo = jax.jit(project).lower(w, x).compile().as_text()
     assert f"f32[8,{width}]" in hlo and " convolution(" in hlo
     assert " reduce(" not in hlo
+
+
+def test_sampler_moves_each_radix_pass_once_on_v5e(one_chip):
+    """The serving sampler at the chat-topp settings, compiled for the chip:
+    each radix pass moves one packed payload with one sort of each row by
+    its destinations and reads its bucket offsets without a gather, so the
+    whole sampler holds one data movement per pass plus the two gathers of
+    the sampling tail (20 such ops, among them 3 sorts of the flattened
+    batch, when every pass moved keys and permutation apart and gathered
+    its offsets)."""
+    from repro.serving.engine import sample_padded_rows
+
+    bits_per_pass = 4
+    passes = 16 // bits_per_pass              # bf16-rounded keys: 16 bits
+
+    def sample(logits, u):
+        return sample_padded_rows(logits, u, p=0.9, temperature=1.0,
+                                  method="matmul", sort_method="radix",
+                                  bits_per_pass=bits_per_pass)
+
+    logits = jax.ShapeDtypeStruct((8, LOGITS_WIDTH), jnp.float32,
+                                  sharding=one_chip)
+    u = jax.ShapeDtypeStruct((8, 1), jnp.float32, sharding=one_chip)
+    hlo = jax.jit(sample).lower(logits, u).compile().as_text()
+    moves = re.findall(r"\s(gather|scatter|sort)\(", hlo)
+    assert 0 < len(moves) <= passes + 2, moves
+    assert moves.count("sort") == passes and "scatter" not in moves, moves
+    sorts = [ln for ln in hlo.splitlines() if " sort(" in ln]
+    assert all(f"u32[8,{LOGITS_WIDTH}]" in ln for ln in sorts), sorts

@@ -170,6 +170,126 @@ def test_radix_sort_bits_per_pass_wider_than_key():
 
 
 # ---------------------------------------------------------------------------
+# unfused passes: one packed payload vs the (keys, permutation) pair
+# ---------------------------------------------------------------------------
+
+
+def _two_payload_passes(enc, bits, k):
+    """The radix passes moving keys and permutation as two payloads: the
+    reference the packed single-payload passes must match bit for bit."""
+    from repro.core.primitives import _multi_split_dest, _scatter_payloads
+    work = enc
+    perm = jnp.broadcast_to(jnp.arange(enc.shape[-1], dtype=jnp.int32),
+                            enc.shape)
+    for shift in range(0, bits, k):
+        kk = min(k, bits - shift)
+        digits = ((work >> shift) & ((1 << kk) - 1)).astype(jnp.int32)
+        dest, _ = _multi_split_dest(digits, 1 << kk, method="vector",
+                                    tile_s=S)
+        work, perm = _scatter_payloads((work, perm), dest, with_indices=False)
+    return work, perm
+
+
+def _gather_dest(digits, num_buckets):
+    """Bucket offsets read with a gather along R and one of the bases."""
+    from repro.core.scan import scan
+    d32 = digits.astype(jnp.int32)
+    oh = (d32[..., None, :] == jnp.arange(num_buckets)[:, None]
+          ).astype(jnp.int8)
+    ex = scan(oh, axis=-1, exclusive=True, method="vector")
+    counts = ex[..., -1] + oh[..., -1].astype(jnp.int32)
+    base = jnp.cumsum(counts, axis=-1) - counts
+    ex_d = jnp.take_along_axis(ex, d32[..., None, :], axis=-2)[..., 0, :]
+    return jnp.take_along_axis(base, d32, axis=-1) + ex_d, counts
+
+
+def _tied_keys(dtype, shape, seed):
+    """Few distinct values, both zeros among them: long runs of ties."""
+    vals = np.asarray([-0.0, 0.0, 1.5, -1.5, 2.0 ** -20, 3.0, -7.25, 0.1],
+                      np.float32)
+    rng = np.random.default_rng(seed)
+    x = np.where(rng.random(shape) < 0.5, rng.choice(vals, shape),
+                 rng.standard_normal(shape))
+    return jnp.asarray(x, dtype)
+
+
+def _moves(fn, *args):
+    """``(primitive, payload dtype)`` of every scatter and sort in ``fn``."""
+    out = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name in ("scatter", "sort"):
+                out.append((eqn.primitive.name,
+                            str(eqn.invars[-1].aval.dtype)))
+            for v in eqn.params.values():
+                if hasattr(v, "jaxpr"):
+                    walk(v.jaxpr)
+                elif hasattr(v, "eqns"):
+                    walk(v)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return out
+
+
+_PACKED_CASES = [(dt, desc, k, (3, 1000))
+                 for dt in ("bfloat16", "float16")
+                 for desc in (False, True) for k in (1, 4, 8)]
+# at 1 bit per pass 16-bit keys carry 15 bits above the index: 2^17 rows
+# fit one word, one row more does not
+_PACKED_CASES += [("bfloat16", True, 1, (1, 131072)),
+                  ("bfloat16", True, 1, (1, 131073))]
+
+
+@pytest.mark.parametrize("dtype,descending,k,shape", _PACKED_CASES)
+def test_packed_radix_passes_match_two_payloads(dtype, descending, k, shape):
+    from repro.core.primitives import _encode_for_sort, dispatch
+    x = _tied_keys(_KEY_DTYPES[dtype], shape, seed=k + shape[-1])
+    enc, bits, _ = _encode_for_sort(x)
+    if descending:
+        enc = ~enc
+    passes = dispatch("radix_passes", "vector")
+
+    def run(e):
+        return passes(e, bits, method="vector", tile_s=S, interpret=None,
+                      bits_per_pass=k)
+
+    work, perm = run(enc)
+    ref_work, ref_perm = _two_payload_passes(enc, bits, k)
+    np.testing.assert_array_equal(np.asarray(work), np.asarray(ref_work))
+    np.testing.assert_array_equal(np.asarray(perm), np.asarray(ref_perm))
+    order = np.argsort(np.asarray(enc), axis=-1, kind="stable")
+    np.testing.assert_array_equal(np.asarray(perm), order)
+    n_passes = -(-bits // k)
+    packed = bits - k + (shape[-1] - 1).bit_length() <= 32
+    assert packed == (shape[-1] != 131073)
+    assert _moves(run, enc) == (
+        [("sort", "uint32")] * n_passes if packed
+        else [("scatter", "uint16"), ("scatter", "int32")] * n_passes)
+    v, i = radix_sort(x, descending=descending, method="vector",
+                      bits_per_pass=k, tile_s=S)
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(ref_perm))
+    np.testing.assert_array_equal(
+        np.asarray(v).view(np.uint16),
+        np.asarray(jnp.take_along_axis(x, ref_perm, axis=-1)).view(np.uint16))
+
+
+@pytest.mark.parametrize("method", ["vector", "matmul", "blocked"])
+@pytest.mark.parametrize("num_buckets,shape", [(2, (77,)), (16, (3, 1000)),
+                                               (256, (2, 333))])
+def test_multi_split_dest_one_hot_matches_gather(method, num_buckets, shape):
+    from repro.core.primitives import _multi_split_dest
+    rng = np.random.default_rng(num_buckets)
+    digits = jnp.asarray(rng.integers(0, num_buckets, shape), jnp.int32)
+    digits = digits.at[..., : shape[-1] // 3].set(num_buckets - 1)  # one big
+    dest, counts = _multi_split_dest(digits, num_buckets, method=method,
+                                     tile_s=S)
+    ref_dest, ref_counts = _gather_dest(digits, num_buckets)
+    np.testing.assert_array_equal(np.asarray(dest), np.asarray(ref_dest))
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(ref_counts))
+
+
+# ---------------------------------------------------------------------------
 # fused pass-count guard (mirrors the bench-smoke CI assertion)
 # ---------------------------------------------------------------------------
 
