@@ -1,5 +1,7 @@
 """The comparison that decides ``correct``: served tokens against the plain
-float32 reference, on a sample of the requests the window finished.
+float32 reference, on a sample of the requests the window finished.  The
+reference is the ``Reference`` of the module that the configuration file
+names under ``"reference"`` (``reference/<module>.py``).
 
 The sample is drawn from the seed and always holds the longest finished
 request.  For each sampled request the reference runs once over its prompt
@@ -40,8 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chip import counts
 from chip.reference import topp
-from chip.reference.decoder import Reference
 
 HERE = Path(__file__).resolve().parent
 SAMPLE = 4
@@ -134,8 +136,9 @@ def readings(cfg: Dict, engine_spec: Dict, stream: List[Dict],
                jax.jit(lambda lg, u: topp.sample(lg, u, p=engine_spec["top_p"],
                                                  temperature=temp)))
     gap_of = jax.jit(lambda lg, a, b: topp.logit_gap(lg, a, b) / temp)
-    ref = Reference(cfg).logits(seed, toks, rows)
-    ctl = Reference(cfg, "fp8").logits(seed, toks, rows) if control else None
+    reference = counts.arch(cfg).Reference
+    ref = reference(cfg).logits(seed, toks, rows)
+    ctl = reference(cfg, "fp8").logits(seed, toks, rows) if control else None
     sides = {"": [], "_control": []}
     for j in range(len(ids)):
         u = jnp.asarray(us[j])

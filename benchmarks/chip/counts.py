@@ -3,9 +3,16 @@
 Everything here is computed from a configuration file's published keys (the
 Hugging Face ``config.json`` names) and the run's token counts, never from the
 compiled program, so a change of implementation cannot move its own yardstick.
+What depends on the architecture (the parameters of a layer, its norms, the
+cache and recurrent state it keeps, its attention FLOPs) comes from the module
+that the file names under ``"reference"``, ``reference/<module>.py``; this
+module only adds them up.
 """
 from __future__ import annotations
 
+import importlib
+import re
+from types import ModuleType
 from typing import Dict
 
 # Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
@@ -34,41 +41,16 @@ def served(cfg: Dict, key: str):
     return dep["served"] if dep is not None else cfg[key]
 
 
+def arch(cfg: Dict) -> ModuleType:
+    """The module that owns the configuration's architecture."""
+    name = cfg["reference"]
+    if not re.fullmatch(r"[A-Za-z_][A-Za-z0-9_]*", name):
+        raise ValueError(f"{cfg['name']}: reference {name!r} is no module name")
+    return importlib.import_module(f"{__package__}.reference.{name}")
+
+
 def head_dim(cfg: Dict) -> int:
     return cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
-
-
-def _attn_params(cfg: Dict) -> int:
-    d, hd = cfg["hidden_size"], head_dim(cfg)
-    q = cfg["num_attention_heads"] * hd
-    kv = cfg["num_key_value_heads"] * hd
-    return d * q + 2 * d * kv + q * d
-
-
-def _norm_params(cfg: Dict) -> int:
-    qk = 2 * head_dim(cfg) if cfg["model_type"] == "qwen3" else 0
-    return 2 * cfg["hidden_size"] + qk
-
-
-def _is_moe_layer(cfg: Dict, layer: int) -> bool:
-    return ("n_routed_experts" in cfg
-            and layer >= cfg.get("first_k_dense_replace", 0)
-            and layer % cfg.get("moe_layer_freq", 1) == 0)
-
-
-def _layer_params(cfg: Dict, layer: int, *, active: bool) -> int:
-    """Matmul parameters of one layer (all experts, or only those a token
-    runs through when ``active``)."""
-    d = cfg["hidden_size"]
-    n = _attn_params(cfg)
-    if _is_moe_layer(cfg, layer):
-        f = cfg["moe_intermediate_size"]
-        routed = cfg["num_experts_per_tok"] if active else cfg["n_routed_experts"]
-        n += d * cfg["n_routed_experts"]                       # router
-        n += (routed + cfg.get("n_shared_experts", 0)) * 3 * d * f
-    else:
-        n += 3 * d * cfg["intermediate_size"]
-    return n
 
 
 def embed_params(cfg: Dict) -> int:
@@ -77,10 +59,11 @@ def embed_params(cfg: Dict) -> int:
 
 def param_count(cfg: Dict) -> int:
     """Parameters the configuration serves (tied head counted once)."""
-    n = sum(_layer_params(cfg, i, active=False) + _norm_params(cfg)
+    m = arch(cfg)
+    n = sum(m.layer_params(cfg, i, active=False)
             for i in range(cfg["num_hidden_layers"]))
-    n += embed_params(cfg) * (1 if served(cfg, "tie_word_embeddings") else 2)
-    return n + cfg["hidden_size"]                              # final norm
+    n += m.norm_params(cfg)
+    return n + embed_params(cfg) * (1 if served(cfg, "tie_word_embeddings") else 2)
 
 
 def weight_bytes(cfg: Dict) -> int:
@@ -88,15 +71,21 @@ def weight_bytes(cfg: Dict) -> int:
 
 
 def kv_bytes_per_token(cfg: Dict) -> int:
-    return (2 * cfg["num_key_value_heads"] * head_dim(cfg)
-            * cfg["num_hidden_layers"] * BYTES[cfg["torch_dtype"]])
+    """Cache bytes one position adds to a row, over all layers."""
+    return arch(cfg).cache_bytes_per_token(cfg)
+
+
+def state_bytes_per_row(cfg: Dict) -> int:
+    """Bytes of one row's recurrent state, whatever its length, over all layers."""
+    return arch(cfg).state_bytes_per_row(cfg)
 
 
 def active_matmul_params(cfg: Dict) -> int:
-    """Matmul parameters one token runs through: every layer's attention
-    projections, the routed and shared experts it is sent to (or the dense
-    MLP), the router, and the output head.  The embedding lookup is no matmul."""
-    return (sum(_layer_params(cfg, i, active=True)
+    """Matmul parameters one token runs through: every layer's own (for MoE
+    the router and the routed and shared experts it is sent to), and the
+    output head.  The embedding lookup is no matmul."""
+    m = arch(cfg)
+    return (sum(m.layer_params(cfg, i, active=True)
                 for i in range(cfg["num_hidden_layers"]))
             + embed_params(cfg))
 
@@ -104,28 +93,31 @@ def active_matmul_params(cfg: Dict) -> int:
 def attn_flops(cfg: Dict, context: int) -> int:
     """Score and value FLOPs of one token attending to ``context`` positions,
     over all layers."""
-    return (4 * cfg["num_attention_heads"] * head_dim(cfg) * context
-            * cfg["num_hidden_layers"])
+    return arch(cfg).attn_flops(cfg, context)
 
 
 def prefill_flops(cfg: Dict, prompt_len: int) -> float:
     """Model FLOPs of a causal prefill: token ``i`` attends to ``i + 1``."""
-    ctx = prompt_len * (prompt_len + 1) // 2
+    m = arch(cfg)
     return (2.0 * active_matmul_params(cfg) * prompt_len
-            + attn_flops(cfg, 1) * ctx)
+            + sum(m.attn_flops(cfg, c) for c in range(1, prompt_len + 1)))
 
 
 def decode_flops(cfg: Dict, contexts) -> float:
     """Model FLOPs of decode tokens, one per entry of ``contexts``."""
+    m = arch(cfg)
     contexts = list(contexts)
     return (2.0 * active_matmul_params(cfg) * len(contexts)
-            + attn_flops(cfg, 1) * sum(contexts))
+            + sum(m.attn_flops(cfg, c) for c in contexts))
 
 
 def decode_step_bytes(cfg: Dict, contexts) -> float:
-    """The least HBM bytes of one dense decode step: every weight once, and
-    the keys and values of each active row's live positions."""
-    return weight_bytes(cfg) + kv_bytes_per_token(cfg) * sum(contexts)
+    """The least HBM bytes of one dense decode step: every weight once, the
+    cache of each active row's live positions, and each row's recurrent state
+    read and written once."""
+    contexts = list(contexts)
+    return (weight_bytes(cfg) + kv_bytes_per_token(cfg) * sum(contexts)
+            + 2 * state_bytes_per_row(cfg) * len(contexts))
 
 
 def sampler_bytes(rows: int, vocab: int) -> int:

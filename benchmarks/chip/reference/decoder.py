@@ -13,6 +13,11 @@ format), which is the only thing the two share.
 ``quant="fp8"`` rounds both operands of every matrix product to
 ``float8_e4m3fn`` with one absmax scale per tensor: the control, computed in
 the precision below the bfloat16 that the configurations state.
+
+A configuration file names this module with ``"reference": "decoder"``; the
+module owns all that depends on the architecture: :data:`PUBLISHED` (what
+``serve_as`` must equal), the counts behind :mod:`chip.counts`, and
+:class:`Reference`.
 """
 from __future__ import annotations
 
@@ -22,7 +27,82 @@ import jax
 import jax.numpy as jnp
 
 from chip import weights
-from chip.counts import head_dim, served
+from chip.counts import BYTES, head_dim, served
+
+# program field (``moe.<field>`` inside the MoE group) -> the published key
+# whose value it must equal, or a function of the file giving that value
+PUBLISHED = {
+    "n_layers": "num_hidden_layers", "d_model": "hidden_size",
+    "n_heads": "num_attention_heads", "n_kv_heads": "num_key_value_heads",
+    "head_dim": head_dim, "d_ff": "intermediate_size",
+    "vocab_size": "vocab_size", "rope_theta": "rope_theta",
+    "norm_eps": "rms_norm_eps",
+    "qk_norm": lambda cfg: cfg["model_type"] == "qwen3",
+    "tie_embeddings": lambda cfg: served(cfg, "tie_word_embeddings"),
+    "moe.n_experts": "n_routed_experts", "moe.top_k": "num_experts_per_tok",
+    "moe.d_ff_expert": "moe_intermediate_size",
+    "moe.n_shared": "n_shared_experts",
+    "moe.first_k_dense": "first_k_dense_replace",
+}
+
+
+# ---- counts (see chip.counts) ----
+
+def _attn_params(cfg: Dict) -> int:
+    d, hd = cfg["hidden_size"], head_dim(cfg)
+    q = cfg["num_attention_heads"] * hd
+    kv = cfg["num_key_value_heads"] * hd
+    return d * q + 2 * d * kv + q * d
+
+
+def _is_moe_layer(cfg: Dict, layer: int) -> bool:
+    return ("n_routed_experts" in cfg
+            and layer >= cfg.get("first_k_dense_replace", 0)
+            and layer % cfg.get("moe_layer_freq", 1) == 0)
+
+
+def layer_params(cfg: Dict, layer: int, *, active: bool) -> int:
+    """Matmul parameters of one layer (all experts, or only those a token
+    runs through when ``active``)."""
+    d = cfg["hidden_size"]
+    n = _attn_params(cfg)
+    if _is_moe_layer(cfg, layer):
+        f = cfg["moe_intermediate_size"]
+        routed = cfg["num_experts_per_tok"] if active else cfg["n_routed_experts"]
+        n += d * cfg["n_routed_experts"]                       # router
+        n += (routed + cfg.get("n_shared_experts", 0)) * 3 * d * f
+    else:
+        n += 3 * d * cfg["intermediate_size"]
+    return n
+
+
+def norm_params(cfg: Dict) -> int:
+    """Every norm weight: two per layer (and Qwen3's per-head q/k norms),
+    then the final norm."""
+    qk = 2 * head_dim(cfg) if cfg["model_type"] == "qwen3" else 0
+    return (cfg["num_hidden_layers"] * (2 * cfg["hidden_size"] + qk)
+            + cfg["hidden_size"])
+
+
+def cache_bytes_per_token(cfg: Dict) -> int:
+    """Keys and values of one position in every layer."""
+    return (2 * cfg["num_key_value_heads"] * head_dim(cfg)
+            * cfg["num_hidden_layers"] * BYTES[cfg["torch_dtype"]])
+
+
+def state_bytes_per_row(cfg: Dict) -> int:
+    """No recurrent state: every layer attends."""
+    return 0
+
+
+def attn_flops(cfg: Dict, context: int) -> int:
+    """Score and value FLOPs of one token attending to ``context`` positions,
+    over all layers."""
+    return (4 * cfg["num_attention_heads"] * head_dim(cfg) * context
+            * cfg["num_hidden_layers"])
+
+
+# ---- the reference forward pass ----
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST

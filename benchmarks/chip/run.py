@@ -1,11 +1,12 @@
 #!/usr/bin/env python3
 """Run one benchmark cell on the chip and print its result line.
 
-    python3 benchmarks/chip/run.py --workload qwen3-4b.chat-topp --seed 7 \\
+    python3 benchmarks/chip/run.py --workload <cell> --seed 7 \\
         --seconds 30 --trace 0
 
 The cell (``workloads`` in ``BENCHMARK.json``) names a configuration file,
-``configs/<config>.json``, and a traffic file, ``traffic/<mix>.json``; with
+``configs/<config>.json``, which names the module of its architecture,
+``reference/<module>.py``, and a traffic file, ``traffic/<mix>.json``; with
 ``--trace 1`` each per-layer metric of the cell is read by its own module,
 ``metrics/<metric>.py``.  One process: weights made on the chip from the seed,
 set-up (every program the window uses compiled or loaded from the
@@ -24,26 +25,19 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+import typing  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
 SAMPLER_CALLS = 10
-
-# program field -> published key whose value it must equal
-SAME = {"n_layers": "num_hidden_layers", "d_model": "hidden_size",
-        "n_heads": "num_attention_heads", "n_kv_heads": "num_key_value_heads",
-        "d_ff": "intermediate_size", "vocab_size": "vocab_size",
-        "rope_theta": "rope_theta", "norm_eps": "rms_norm_eps"}
-SAME_MOE = {"n_experts": "n_routed_experts", "top_k": "num_experts_per_tok",
-            "d_ff_expert": "moe_intermediate_size", "n_shared": "n_shared_experts",
-            "first_k_dense": "first_k_dense_replace"}
 
 
 def info(msg: str) -> None:
@@ -64,22 +58,73 @@ def load_cell(name: str):
             [m for m in bench["per_layer"] if listed(m)])
 
 
+def _published(cfg, sa, table):
+    """The names of the ``serve_as`` fields (``group.field`` inside a nested
+    group) that differ from what ``table`` says the file publishes: a key,
+    whose value in the file is the served one (a ``reduced`` key's cut
+    value), or a function of the file.  A field that neither side has is
+    no part of the architecture and is skipped."""
+    missing = object()
+    bad = []
+    for field, want in table.items():
+        got = sa
+        for part in field.split("."):
+            got = got.get(part, missing) if isinstance(got, dict) else missing
+        want = want(cfg) if callable(want) else cfg.get(want, missing)
+        if got is missing and want is missing:
+            continue
+        got, want = (tuple(v) if isinstance(v, list) else v for v in (got, want))
+        if got is missing or want is missing or got != want:
+            bad.append(field)
+    return bad
+
+
+def _dataclass(cls, values, where=""):
+    """``cls(**values)``, a nested dict made into the sub-configuration its
+    field's type names and a list into a tuple; an unknown key raises."""
+    hints = typing.get_type_hints(cls)
+    kw = {}
+    for k, v in values.items():
+        if k not in hints:
+            raise ValueError(f"serve_as key {where}{k} is no field of {cls.__name__}")
+        if isinstance(v, dict):
+            sub = [t for t in (hints[k], *typing.get_args(hints[k]))
+                   if dataclasses.is_dataclass(t)]
+            if not sub:
+                raise ValueError(f"serve_as key {where}{k} holds a group, but "
+                                 f"{cls.__name__}.{k} is no sub-configuration")
+            v = _dataclass(sub[0], v, f"{where}{k}.")
+        elif isinstance(v, list):
+            v = tuple(v)
+        kw[k] = v
+    return cls(**kw)
+
+
 def model_config(cfg):
     """The program's ``ModelConfig`` from the file's ``serve_as``, checked
-    against the published keys it stands for."""
-    from repro.configs.base import ModelConfig, MoEConfig
-    from chip.counts import head_dim
-    sa = dict(cfg["serve_as"])
-    moe = sa.pop("moe", None)
-    pairs = [(sa[k], cfg[v], k) for k, v in SAME.items()]
-    pairs.append((sa["head_dim"], head_dim(cfg), "head_dim"))
-    if moe:
-        pairs += [(moe[k], cfg[v], k) for k, v in SAME_MOE.items()]
-    bad = [k for a, b, k in pairs if a != b]
+    against the published keys it stands for (the table ``PUBLISHED`` of the
+    file's own ``reference`` module)."""
+    from repro.configs.base import ModelConfig
+    from chip.counts import arch
+    table = arch(cfg).PUBLISHED
+    published = cfg.get("published", {})
+    reduced = cfg.get("reduced", [])
+    for k in reduced:
+        if k not in published:
+            raise ValueError(f"{cfg['name']}: reduced key {k} has no source "
+                             "value under published")
+        if k not in table.values():
+            raise ValueError(f"{cfg['name']}: reduced key {k} is not checked "
+                             "against serve_as by the reference module")
+    extra = set(published) - set(reduced)
+    if extra:
+        raise ValueError(f"{cfg['name']}: published keys {sorted(extra)} are "
+                         "not listed under reduced")
+    bad = _published(cfg, cfg["serve_as"], table)
     if bad:
         raise ValueError(f"{cfg['name']}: serve_as differs from the published "
                          f"keys in {bad}")
-    return ModelConfig(name=cfg["name"], moe=MoEConfig(**moe) if moe else None, **sa)
+    return _dataclass(ModelConfig, {**cfg["serve_as"], "name": cfg["name"]})
 
 
 def p95(xs):

@@ -4,7 +4,8 @@ from __future__ import annotations
 import copy
 
 QWEN = {
-    "name": "tiny-qwen3", "model_type": "qwen3", "departures": {},
+    "name": "tiny-qwen3", "reference": "decoder", "model_type": "qwen3",
+    "departures": {},
     "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
     "vocab_size": 500, "rms_norm_eps": 1e-6, "rope_theta": 1000000,
@@ -17,7 +18,7 @@ QWEN = {
 }
 
 MOE = {
-    "name": "tiny-deepseek-moe", "model_type": "deepseek",
+    "name": "tiny-deepseek-moe", "reference": "decoder", "model_type": "deepseek",
     "departures": {"tie_word_embeddings": {"served": True, "why": ""},
                    "norm_topk_prob": {"served": True, "why": ""}},
     "hidden_size": 64, "intermediate_size": 256, "moe_intermediate_size": 32,

@@ -107,3 +107,31 @@ def test_weights_per_layer_match_the_stacked_build(qwen):
     assert vals.min() >= -62 and vals.max() <= 62 and np.all(vals == np.round(vals))
     # zero mean: a biased generator collapses every position onto one output
     assert abs(vals.mean()) < 4 * 18.5 / vals.size ** 0.5 and 17 < vals.std() < 20
+
+
+# correct.readings of a fixed sample (with the fp8 control) as the harness
+# read them before the reference came from the configuration's own module
+READINGS = {
+    ("QWEN", "greedy"): (1.2611780166625977, 1.0, 0.0, 0.0),
+    ("QWEN", "topp_scan"): (0.46996042132377625, 1.0, 0.031070958822965622,
+                            0.9310344827586207),
+    ("MOE", "greedy"): (1.1207995414733887, 1.0, 0.0, 0.0),
+    ("MOE", "topp_scan"): (0.44767871499061584, 1.0, 0.019267559051513672,
+                           0.8620689655172413),
+}
+
+
+@pytest.mark.parametrize("case, sampler", sorted(READINGS))
+def test_readings_pinned(case, sampler):
+    import chip_cases
+    rng = np.random.default_rng(7)
+    stream, served = [], {}
+    for i, (p, n) in enumerate(((8, 4), (13, 6), (5, 3), (24, 16))):
+        stream.append({"tokens": rng.integers(0, 500, p, dtype=np.int32),
+                       "key": np.array([i, 2**31 + i], np.uint32),
+                       "max_new_tokens": n})
+        served[i] = [int(x) for x in rng.integers(0, 500, n)]
+    got = correct.readings(getattr(chip_cases, case), traffic(sampler)["engine"],
+                           stream, served, [0, 1, 2, 3], 2**31 + 5, control=True)
+    assert (got["logit_gap"], got["mismatch_share"], got["logit_gap_control"],
+            got["mismatch_share_control"]) == READINGS[case, sampler]
